@@ -59,7 +59,7 @@ def bench_primitive(n: int, cut: int, k: int = 2, repeat: int = 0):
     assert block is not None
 
     dt, got_k = timeit(lambda: ops.cutjoin_reduce(Ms, distinct=cut >= 2,
-                                                  bm=block, bn=block),
+                                                  block=block),
                        repeat=repeat, warmup=True)
     emit(f"cutjoin/kernel/n={n}/cut={cut}", dt * 1e6)
 
@@ -68,7 +68,7 @@ def bench_primitive(n: int, cut: int, k: int = 2, repeat: int = 0):
         mask = 1.0 - np.eye(n)              # prebuilt: amortises the XLA tier
 
     def xla_join():
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             stack = [jnp.asarray(M) for M in Ms]
             if mask is not None:
                 stack.append(jnp.asarray(mask))
@@ -107,7 +107,7 @@ def bench_primitive3(n: int, mix: str, repeat: int = 5):
     emit(f"cutjoin/kernel3/{mix}/n={n}", dt_k * 1e6)
 
     def xla_join():
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             stack = [jnp.asarray(np.broadcast_to(
                 M.reshape(tuple(n if a in ax else 1 for a in range(3))),
                 (n, n, n))) for M, ax in zip(Ms, axes)]

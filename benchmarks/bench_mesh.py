@@ -64,7 +64,7 @@ def bench_layer1(batch: int, n: int, repeat: int = 5):
 
     def serial():
         return np.asarray([ops.cutjoin_reduce(list(s), distinct=True,
-                                              bm=block, bn=block)
+                                              block=block)
                            for s in stacks])
 
     dt_s, got_s = timeit(serial, repeat=repeat, warmup=True)
@@ -91,7 +91,7 @@ def bench_layer2(n: int, cut: int, repeat: int = 3):
     assert block is not None
 
     dt_1, got_1 = timeit(lambda: ops.cutjoin_reduce(Ms, distinct=cut >= 2,
-                                                    bm=block, bn=block),
+                                                    block=block),
                          repeat=repeat, warmup=True)
     emit(f"mesh/join-single/n={n}/cut={cut}", dt_1 * 1e6)
 
@@ -170,6 +170,13 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI configuration")
     args = ap.parse_args(argv)
+    import jax
+    devs = jax.devices()
+    if len(devs) < 2:
+        raise SystemExit(
+            f"bench_mesh measures a mesh and needs at least 2 devices; "
+            f"the {devs[0].platform} backend has {len(devs)} (on the CPU, "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 
     if args.smoke:
         batch, bn, join_n, tri_n, con_n = 64, 64, 512, 160, 192

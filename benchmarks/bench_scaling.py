@@ -2,8 +2,9 @@
 scaling cannot be *measured* here; instead we (a) verify work-partitioned
 execution (block-cyclic units) has low partitioning overhead — the
 property that yields the paper's near-linear scaling when units run on
-independent workers — and (b) run the sharded-einsum path on forced host
-devices in a subprocess to confirm multi-device execution."""
+independent workers — and (b) run the sharded-einsum path on 8 forced
+host CPU devices in a CPU-only subprocess to confirm multi-device
+execution (it raises if the child fails)."""
 from __future__ import annotations
 
 import os
@@ -34,7 +35,8 @@ def run(scale: str = "small"):
         assert abs(v - base) < 1e-6 * max(1.0, base)
         emit(f"scaling/blocks/{nb}", t * 1e6,
              f"overhead={t / t1:.2f}x")
-    # sharded execution across forced host devices (subprocess)
+    # sharded execution across forced host devices: a CPU-only child,
+    # so it never contends with this process for an accelerator
     code = textwrap.dedent("""
         import jax, numpy as np, time
         from repro.graph.generators import erdos_renyi
@@ -47,13 +49,14 @@ def run(scale: str = "small"):
         t0 = time.perf_counter(); v = sharded_hom_count(chain(5), A, mesh)
         print(f"SHARDED_OK {time.perf_counter()-t0:.3f}")
     """)
-    env = dict(os.environ, PYTHONPATH=SRC,
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=560)
-    ok = "SHARDED_OK" in r.stdout
-    emit("scaling/sharded_8dev", 0.0 if not ok else float(
-        r.stdout.split()[-1]) * 1e6, f"ok={ok}")
+    if r.returncode != 0 or "SHARDED_OK" not in r.stdout:
+        raise RuntimeError(f"8-device CPU child failed (rc "
+                           f"{r.returncode}):\n{r.stderr[-2000:]}")
+    emit("scaling/sharded_8dev_cpu", float(r.stdout.split()[-1]) * 1e6)
 
 
 if __name__ == "__main__":

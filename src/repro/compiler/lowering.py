@@ -521,7 +521,7 @@ class CompiledPlan:
                 if node.cut_size <= 2:
                     return ops.cutjoin_reduce(Ms,
                                               distinct=node.cut_size >= 2,
-                                              bm=block, bn=block)
+                                              block=block)
                 return ops.cutjoin_reduce3(Ms, axes, n=self.graph.n,
                                            block=block)
             # factor magnitudes exceed what chunked f32 can represent
@@ -592,7 +592,7 @@ class CompiledPlan:
                 self._annotate(route="kernel-keep")
                 if node.cut_size == 2:
                     out = ops.cutjoin_reduce_keep(Ms, keep=axis,
-                                                  bm=block, bn=block)
+                                                  block=block)
                 else:
                     out = ops.cutjoin_reduce3_keep(Ms, axes, keep=axis,
                                                    n=self.graph.n,

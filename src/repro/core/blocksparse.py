@@ -63,7 +63,7 @@ def triangle_count_blocksparse(bsa: BlockSparseAdjacency,
     For each non-empty output tile (i,j), accumulate A[i,k] @ A[k,j] over
     k where BOTH factor tiles exist, then mask with A[i,j] and reduce —
     per-tile this is exactly kernels/matreduce (use_kernel=True routes
-    through the Pallas op in interpret mode for validation).
+    through the Pallas op — compiled on TPU, interpreted elsewhere).
     """
     total = 0.0
     for (i, j), mask in bsa.blocks.items():
@@ -81,8 +81,7 @@ def triangle_count_blocksparse(bsa: BlockSparseAdjacency,
             lhs = np.concatenate([bsa.blocks[(i, k)] for k in ks], axis=1)
             rhs = np.concatenate([bsa.blocks[(k, j)].T for k in ks], axis=1)
             total += float(ops.masked_matmul_reduce(
-                jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(mask),
-                interpret=True))
+                jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(mask)))
         else:
             total += float((acc * mask).sum())
     return total / 6.0

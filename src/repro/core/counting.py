@@ -5,7 +5,7 @@ tensorised form of the paper's cross-pattern computation reuse: all
 concrete patterns of an application (e.g. the 112 6-motifs) draw from one
 shared pool of quotient hom contractions.
 
-Counts run in f64 (jax.experimental.enable_x64 scoped locally) — exact up
+Counts run in f64 (jax.enable_x64 scoped locally) — exact up
 to 2^53, enough for trillion-scale embedding counts.
 """
 from __future__ import annotations
@@ -39,7 +39,7 @@ class CountingEngine:
         self.graph = graph
         self.budget = budget
         self.use_x64 = use_x64
-        self._x64 = jax.experimental.enable_x64 if use_x64 else _nullctx
+        self._x64 = jax.enable_x64 if use_x64 else _nullctx
         self._np_dtype = np.float64 if use_x64 else np.float32
         # sharded-contraction binding: a 1-D ("data",) mesh routes hom /
         # hom_free_tensor through ``distributed.contract`` (row-sharded

@@ -25,6 +25,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.apct import APCT
 from repro.core.counting import CountingEngine
 from repro.core.decomposition import cutting_sets, subpatterns
@@ -95,6 +96,7 @@ class MiningEngine:
                 return val
             except Exception:
                 self.compiler_fallbacks += 1    # legacy path takes over
+                obs.counter("engine.compiler_fallbacks")
         if cut == "auto":
             cut = self.choose_cut(p)
         if induced == "edge":

@@ -24,6 +24,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import obs
 from repro.core.counting import CountingEngine
 from repro.core.pattern import Pattern
 from repro.graph.storage import Graph
@@ -125,6 +126,7 @@ def _level_supports(g: Graph, level: list, counter: CountingEngine,
             return supports
         except Exception:
             res.fallbacks += 1
+            obs.counter("fsm.compile_fallbacks")
     return {p: support_fn(counter, p) for p in level}
 
 

@@ -46,7 +46,7 @@ uneven sharding, so the trim replicates the finished tensor
 (``contract.trim_gathers`` counts it — the adjacency itself still
 never materialises unsharded either way).
 
-Callers hold ``jax.experimental.enable_x64`` while calling (the engine
+Callers hold ``jax.enable_x64`` while calling (the engine
 does), so factors and steps trace in f64.  All ``shard_map`` call sites
 go through ``meshes.sharding_ctx`` — the repo's ``mesh-guard`` lint
 rule — so logical-axis ``constrain`` calls by surrounding code resolve
@@ -60,7 +60,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -140,8 +139,8 @@ def _step_fn(mesh: Mesh, spec: str, shard_axes: tuple, ranks: tuple,
                      for r, ax in zip(ranks, shard_axes))
     out_specs = P(*(("data",) if out_sharded else (None,))
                   + (None,) * (out_rank - 1)) if out_rank else P()
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False))
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):

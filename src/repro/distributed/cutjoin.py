@@ -51,15 +51,14 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
 from repro.distributed import meshes
 from repro.kernels import matreduce as _mr
-from repro.kernels.ops import _auto_interpret
+from repro.kernels.ops import _auto_interpret, _tile
 
-_x64 = jax.experimental.enable_x64
+_x64 = jax.enable_x64
 
 # re-exported so GPM callers need only this module
 data_mesh = meshes.data_mesh
@@ -68,10 +67,6 @@ num_shards = meshes.num_shards
 
 def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-def _default_block(block, interpret) -> int:
-    return block if block is not None else (1024 if interpret else 128)
 
 
 def _pad_axis(x, axis: int, size: int):
@@ -86,52 +81,60 @@ def _pad_axis(x, axis: int, size: int):
 
 # -- layer 2: block-sharded joins ---------------------------------------------------
 
-@functools.lru_cache(maxsize=None)
-def _pair_scalar_fn(mesh: Mesh, distinct: bool, b: int, rows: int,
-                    interpret: bool):
-    """shard_map'd scalar pair join: local (k, rows, N) row-slice ->
-    per-shard f64 partial -> psum.  Cached per (mesh, statics) so
-    serving plans trace once."""
-    def local(stack):                       # (k, rows, N) on this shard
-        off = jnp.stack([jax.lax.axis_index("data") * rows,
-                         jnp.int32(0)]).astype(jnp.int32)
-        tiles = _mr._pairjoin_tiles(stack, off, distinct=distinct,
-                                    bm=b, bn=b, interpret=interpret)
-        part = jnp.sum(tiles.astype(jnp.float64))
-        return jax.lax.psum(part, "data")
-
-    jfn = jax.jit(shard_map(local, mesh,
-                            in_specs=(P(None, "data", None),),
-                            out_specs=P(), check_rep=False))
-
-    def call(*args):
-        with meshes.sharding_ctx(mesh):
-            return jfn(*args)
-
-    return call
+def _shard_offsets(rows: int, q: int, naxes: int):
+    """Per-axis global offsets of this shard's slice: ``rows`` per shard
+    along kernel axis ``q``, zero elsewhere."""
+    start = jax.lax.axis_index("data") * rows
+    return jnp.stack([start if a == q else jnp.int32(0)
+                      for a in range(naxes)]).astype(jnp.int32)
 
 
 @functools.lru_cache(maxsize=None)
-def _vec_scalar_fn(mesh: Mesh, b: int, interpret: bool):
-    """shard_map'd |cut| = 1 join: local (k, cols) column-slice ->
-    per-shard f64 partial -> psum (no mask, so no offsets needed)."""
+def _pair_fn(mesh: Mesh, distinct: bool, keep: bool, chunk: int, tr: int,
+             tc: int, rows: int, q: int, interpret: bool):
+    """shard_map'd pair join over a (k, M, N) stack sharded on kernel
+    axis ``q`` (0 = rows, 1 = the lane axis), ``rows`` per shard.  A
+    scalar or a per-column partial vector ``psum``s; a kept column axis
+    that is itself sharded (keep, q == 1) concatenates its output
+    slices.  Cached per (mesh, statics) so serving plans trace once."""
     def local(stack):
-        tiles = _mr._vecjoin_tiles(stack, bn=b, interpret=interpret)
-        part = jnp.sum(tiles.astype(jnp.float64))
-        return jax.lax.psum(part, "data")
+        out = _mr._pairjoin(stack, _shard_offsets(rows, q, 2),
+                            distinct=distinct, keep=keep, chunk=chunk,
+                            tr=tr, tc=tc, interpret=interpret)
+        return out if keep and q == 1 else jax.lax.psum(out, "data")
 
-    jfn = jax.jit(shard_map(local, mesh, in_specs=(P(None, "data"),),
-                            out_specs=P(), check_rep=False))
+    spec = P(None, "data", None) if q == 0 else P(None, None, "data")
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec,),
+                                out_specs=P("data") if keep and q == 1
+                                else P(), check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):
             return jfn(*args)
 
     return call
+
+
+def _sharded_pair(stack, *, mesh, distinct, keep, q, block, tile,
+                  interpret):
+    """Pad a (k, M, N) stack so kernel axis ``q`` splits into
+    tile-aligned shards, then run the shard_map'd pair join."""
+    d = num_shards(mesh)
+    M, N = stack.shape[1], stack.shape[2]
+    local = [M, N]
+    local[q] = _ceil_to(local[q], d) // d
+    tr, tc, c = _mr._pair_tiles(local[0], local[1], block or tile, tile)
+    tiles = (tr, tc)
+    size = [_ceil_to(M, tr), _ceil_to(N, tc)]
+    size[q] = _ceil_to(local[q] * d, d * tiles[q])
+    stack = _pad_axis(_pad_axis(stack, 1, size[0]), 2, size[1])
+    fn = _pair_fn(mesh, distinct, keep, c, tr, tc, size[q] // d, q,
+                  interpret)
+    return fn(stack)
 
 
 def sharded_cutjoin(factors, *, mesh: Mesh, distinct: bool = True,
-                    block: Optional[int] = None,
+                    block: Optional[int] = None, tile: Optional[int] = None,
                     interpret: Optional[bool] = None) -> float:
     """|cut| <= 2 decomposition join sharded over cut axis 0 — the mesh
     analogue of ``ops.cutjoin_reduce``.  ``block`` must come from the
@@ -139,42 +142,59 @@ def sharded_cutjoin(factors, *, mesh: Mesh, distinct: bool = True,
     chunk): the sharded route inherits the single-device exactness
     contract and is only bit-for-bit under it."""
     interpret = _auto_interpret(interpret)
-    d = num_shards(mesh)
-    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
+    stack = _mr._pair_stack(factors)         # vectors: (k, n/128, 128)
     with _x64():
-        if stack.ndim == 2:                  # |cut| = 1: vector fast path
-            N = stack.shape[1]
-            b = min(_default_block(block, interpret),
-                    max(_ceil_to(max(N, 1), d) // d, 1))
-            stack = _pad_axis(stack, 1, _ceil_to(max(N, 1), d * b))
-            return float(_vec_scalar_fn(mesh, b, interpret)(stack))
-        assert stack.ndim == 3
-        M, N = stack.shape[1], stack.shape[2]
-        b = min(_default_block(block, interpret), max(min(M, N), 1))
-        Mp = _ceil_to(M, d * b)
-        stack = _pad_axis(_pad_axis(stack, 1, Mp), 2, _ceil_to(N, b))
-        return float(_pair_scalar_fn(mesh, distinct, b, Mp // d,
-                                     interpret)(stack))
+        return float(_sharded_pair(
+            stack, mesh=mesh, distinct=distinct and np.ndim(factors[0]) == 2,
+            keep=False, q=0, block=block, tile=_tile(interpret, tile),
+            interpret=interpret))
+
+
+def sharded_cutjoin_keep(factors, *, keep: int = 0, mesh: Mesh,
+                         distinct: bool = True,
+                         block: Optional[int] = None,
+                         tile: Optional[int] = None,
+                         interpret: Optional[bool] = None) -> np.ndarray:
+    """Keep-axis |cut| = 2 join sharded over original cut axis 0 — the
+    mesh analogue of ``ops.cutjoin_reduce_keep``.  The kernel keeps its
+    lane axis, so keep == 0 transposes: original axis 0 then rides the
+    lanes and each shard owns a slice of the output; keep == 1 shards
+    the reduced rows and ``psum``s per-shard partial vectors.  Same
+    ``exact_block`` contract as the scalar routes."""
+    interpret = _auto_interpret(interpret)
+    assert keep in (0, 1)
+    stack = _mr._pair_stack(factors)
+    assert stack.shape[1] == stack.shape[2]
+    n = stack.shape[1]
+    if keep == 0:
+        stack = jnp.swapaxes(stack, 1, 2)    # kept axis onto the lanes
+    with _x64():
+        out = _sharded_pair(stack, mesh=mesh, distinct=distinct, keep=True,
+                            q=1 if keep == 0 else 0, block=block,
+                            tile=_tile(interpret, tile), interpret=interpret)
+        return np.asarray(out, np.float64)[:n]
 
 
 @functools.lru_cache(maxsize=None)
-def _tri_scalar_fn(mesh: Mesh, present: tuple, distinct: bool, b: int,
-                   rows: int, interpret: bool):
-    """shard_map'd scalar tri join: factors carrying axis 0 arrive
-    row-sliced, the rest replicated; per-shard f64 partial -> psum."""
+def _tri_fn(mesh: Mesh, present: tuple, distinct: bool, keep: bool,
+            chunk: int, tiles: tuple, rows: int, q: int, interpret: bool):
+    """shard_map'd tri join: factors carrying kernel axis ``q`` arrive
+    sliced along it (``rows`` per shard), the rest replicated.  A kept
+    x axis that is itself sharded (keep, q == 0) concatenates its output
+    slices; everything else ``psum``s."""
     def local(*stacked):
-        off = jnp.stack([jax.lax.axis_index("data") * rows,
-                         jnp.int32(0), jnp.int32(0)]).astype(jnp.int32)
-        tiles = _mr._trijoin_tiles(*stacked, offsets=off, present=present,
-                                   distinct=distinct, bm=b, bn=b, bk=b,
-                                   interpret=interpret)
-        part = jnp.sum(tiles.astype(jnp.float64))
-        return jax.lax.psum(part, "data")
+        bx, by, bz = tiles
+        out = _mr._trijoin(*stacked, offsets=_shard_offsets(rows, q, 3),
+                           present=present, distinct=distinct, keep=keep,
+                           chunk=chunk, bx=bx, by=by, bz=bz,
+                           interpret=interpret)
+        return out if keep and q == 0 else jax.lax.psum(out, "data")
 
-    in_specs = tuple(P("data", None, None) if 0 in ax else P(None, None, None)
-                     for ax in present)
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=P(), check_rep=False))
+    in_specs = tuple(P(*[("data" if a == q else None)
+                         for a in _mr.tri_layout(ax)]) for ax in present)
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                out_specs=P("data") if keep and q == 0
+                                else P(), check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):
@@ -183,131 +203,44 @@ def _tri_scalar_fn(mesh: Mesh, present: tuple, distinct: bool, b: int,
     return call
 
 
-def _tri_prepare(factors, axes, n: int, d: int, b: int, shard_axis: int):
-    """Normalise tri factors (3-D views, tile padding, injected
-    ones-vectors) and extra-pad ``shard_axis`` carriers to the shard x
-    tile multiple so every shard's slice is tile-aligned."""
-    stacked, present = _mr._tri_normalise(factors, axes, n, b)
-    size = _ceil_to(_ceil_to(n, b), d * b)
-    out = [_pad_axis(F, shard_axis, size) if shard_axis in ax else F
-           for F, ax in zip(stacked, present)]
-    return out, present, size
+def _sharded_tri(factors, axes, *, n, mesh, distinct, keep, q, block,
+                 tile, interpret):
+    """Normalise tri factors (compact layouts, tile padding, injected
+    ones-vectors), extra-pad kernel axis ``q`` carriers to the shard x
+    tile multiple so every shard's slice is tile-aligned, and run the
+    shard_map'd tri join."""
+    d = num_shards(mesh)
+    bx, by, bz, c = _mr._tri_tiles(n, block or tile, tile)
+    tiles = (bx, by, bz)
+    stacked, present = _mr._tri_normalise(factors, axes, n, tiles)
+    size = _ceil_to(_ceil_to(n, tiles[q]), d * tiles[q])
+    stacked = [_pad_axis(F, _mr.tri_layout(ax).index(q), size)
+               if q in ax else F for F, ax in zip(stacked, present)]
+    fn = _tri_fn(mesh, present, distinct, keep, c, tiles, size // d, q,
+                 interpret)
+    return fn(*stacked)
 
 
 def sharded_cutjoin3(factors, axes, *, n: int, mesh: Mesh,
                      distinct: bool = True, block: Optional[int] = None,
+                     tile: Optional[int] = None,
                      interpret: Optional[bool] = None) -> float:
     """|cut| = 3 decomposition join sharded over cut axis 0 — the mesh
     analogue of ``ops.cutjoin_reduce3``.  Axis-subset factors are sliced
     only when they carry axis 0, else replicated to every device; the
     same ``exact_block`` contract as ``sharded_cutjoin`` applies."""
     interpret = _auto_interpret(interpret)
-    d = num_shards(mesh)
-    cap = _default_block(block, interpret)
-    b = min(cap if interpret else min(cap, 128), max(n, 1))
     with _x64():
-        stacked, present, size = _tri_prepare(factors, axes, n, d, b, 0)
-        fn = _tri_scalar_fn(mesh, present, distinct, b, size // d,
-                            interpret)
-        return float(fn(*stacked))
-
-
-@functools.lru_cache(maxsize=None)
-def _pair_keep_fn(mesh: Mesh, distinct: bool, b: int, rows: int, q: int,
-                  interpret: bool):
-    """shard_map'd keep-axis pair join.  ``q`` is the position of the
-    *sharded* (original cut-0) axis after the kept axis was moved to the
-    front: q == 0 means the kept axis itself is sharded (each shard owns
-    a slice of the output -> concatenate via out_specs), q == 1 means
-    the reduced axis is sharded (each shard holds a partial output
-    vector -> psum)."""
-    def local(stack):
-        start = jax.lax.axis_index("data") * rows
-        off = jnp.stack([start, jnp.int32(0)]).astype(jnp.int32) \
-            if q == 0 else \
-            jnp.stack([jnp.int32(0), start]).astype(jnp.int32)
-        tiles = _mr._pairjoin_keep_tiles(stack, off, distinct=distinct,
-                                         bm=b, bn=b, interpret=interpret)
-        vec = jnp.sum(tiles.astype(jnp.float64), axis=1)
-        return vec if q == 0 else jax.lax.psum(vec, "data")
-
-    in_specs = (P(None, "data", None),) if q == 0 \
-        else (P(None, None, "data"),)
-    out_specs = P("data") if q == 0 else P()
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False))
-
-    def call(*args):
-        with meshes.sharding_ctx(mesh):
-            return jfn(*args)
-
-    return call
-
-
-def sharded_cutjoin_keep(factors, *, keep: int = 0, mesh: Mesh,
-                         distinct: bool = True,
-                         block: Optional[int] = None,
-                         interpret: Optional[bool] = None) -> np.ndarray:
-    """Keep-axis |cut| = 2 join sharded over original cut axis 0 — the
-    mesh analogue of ``ops.cutjoin_reduce_keep``.  keep == 0 shards the
-    output itself (all-gather via out_specs); keep == 1 shards the
-    reduced axis and ``psum``s per-shard partial vectors.  Same
-    ``exact_block`` contract as the scalar routes."""
-    interpret = _auto_interpret(interpret)
-    assert keep in (0, 1)
-    d = num_shards(mesh)
-    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
-    assert stack.ndim == 3 and stack.shape[1] == stack.shape[2]
-    n = stack.shape[1]
-    if keep == 1:
-        stack = jnp.swapaxes(stack, 1, 2)    # kept axis leads the kernel
-    q = 0 if keep == 0 else 1                # where original axis 0 sits
-    b = min(_default_block(block, interpret), max(n, 1))
-    size = _ceil_to(_ceil_to(n, b), d * b)
-    with _x64():
-        stack = _pad_axis(_pad_axis(stack, 1 + q, size), 2 - q,
-                          _ceil_to(n, b))
-        fn = _pair_keep_fn(mesh, distinct, b, size // d, q, interpret)
-        return np.asarray(fn(stack), np.float64)[:n]
-
-
-@functools.lru_cache(maxsize=None)
-def _tri_keep_fn(mesh: Mesh, present: tuple, distinct: bool, b: int,
-                 rows: int, q: int, interpret: bool):
-    """shard_map'd keep-axis tri join; ``q`` as in ``_pair_keep_fn`` —
-    the sharded (original cut-0) axis is the kernel's leading (kept)
-    axis when q == 0, its first reduced axis when q == 1."""
-    def local(*stacked):
-        start = jax.lax.axis_index("data") * rows
-        zero = jnp.int32(0)
-        off = jnp.stack([start, zero, zero]).astype(jnp.int32) \
-            if q == 0 else \
-            jnp.stack([zero, start, zero]).astype(jnp.int32)
-        tiles = _mr._trijoin_tiles(*stacked, offsets=off, present=present,
-                                   distinct=distinct, bm=b, bn=b, bk=b,
-                                   interpret=interpret)
-        vec = jnp.sum(tiles.astype(jnp.float64), axis=(1, 2))
-        return vec if q == 0 else jax.lax.psum(vec, "data")
-
-    def spec(ax):
-        return P(*[("data" if a == q and q in ax else None)
-                   for a in range(3)])
-
-    in_specs = tuple(spec(ax) for ax in present)
-    out_specs = P("data") if q == 0 else P()
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False))
-
-    def call(*args):
-        with meshes.sharding_ctx(mesh):
-            return jfn(*args)
-
-    return call
+        return float(_sharded_tri(factors, axes, n=n, mesh=mesh,
+                                  distinct=distinct, keep=False, q=0,
+                                  block=block, tile=_tile(interpret, tile),
+                                  interpret=interpret))
 
 
 def sharded_cutjoin3_keep(factors, axes, *, keep: int, n: int,
                           mesh: Mesh, distinct: bool = True,
                           block: Optional[int] = None,
+                          tile: Optional[int] = None,
                           interpret: Optional[bool] = None) -> np.ndarray:
     """Keep-axis |cut| = 3 join sharded over original cut axis 0 — the
     mesh analogue of ``ops.cutjoin_reduce3_keep``.  Factors are
@@ -316,26 +249,13 @@ def sharded_cutjoin3_keep(factors, axes, *, keep: int, n: int,
     kernel position 0 (keep == 0: output slices, all-gather) or 1
     (keep != 0: partial vectors, psum)."""
     interpret = _auto_interpret(interpret)
-    assert keep in (0, 1, 2)
-    perm = (keep,) + tuple(a for a in range(3) if a != keep)
-    rank = {a: i for i, a in enumerate(perm)}
-    paxes, pfactors = [], []
-    for F, ax in zip(factors, axes):
-        ax = tuple(ax)
-        new = tuple(sorted(rank[a] for a in ax))
-        order = tuple(ax.index(perm[a]) for a in new)
-        pfactors.append(np.transpose(np.asarray(F), order)
-                        if order != tuple(range(len(ax))) else F)
-        paxes.append(new)
-    q = perm.index(0)                        # 0 iff keep == 0, else 1
-    d = num_shards(mesh)
-    cap = _default_block(block, interpret)
-    b = min(cap if interpret else min(cap, 128), max(n, 1))
+    pfactors, paxes, perm = _mr.tri_permute(factors, axes, keep)
     with _x64():
-        stacked, present, size = _tri_prepare(pfactors, paxes, n, d, b, q)
-        fn = _tri_keep_fn(mesh, present, distinct, b, size // d, q,
-                          interpret)
-        return np.asarray(fn(*stacked), np.float64)[:n]
+        out = _sharded_tri(pfactors, paxes, n=n, mesh=mesh,
+                           distinct=distinct, keep=True, q=perm.index(0),
+                           block=block, tile=_tile(interpret, tile),
+                           interpret=interpret)
+        return np.asarray(out, np.float64)[:n]
 
 
 @functools.lru_cache(maxsize=None)
@@ -347,8 +267,8 @@ def _dense_scalar_fn(mesh: Mesh, k: int):
         return jax.lax.psum(jnp.sum(jnp.prod(stack, axis=0)), "data")
 
     in_specs = (P(*([None, "data"] + [None] * (k - 1))),)
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=P(), check_rep=False))
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(), check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):
@@ -385,8 +305,8 @@ def _dense_keep_fn(mesh: Mesh, k: int, keep: int):
 
     in_specs = (P(None, "data", *([None] * (k - 1))),)
     out_specs = P("data") if keep == 0 else P(None)
-    jfn = jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False))
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):
@@ -434,9 +354,9 @@ def _batch_pair_fn(mesh: Mesh, distinct: bool):
             prod = jnp.where(rows != cols, prod, 0.0)
         return jnp.sum(prod, axis=(1, 2))
 
-    jfn = jax.jit(shard_map(local, mesh,
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh,
                             in_specs=(P("data", None, None, None),),
-                            out_specs=P("data"), check_rep=False))
+                            out_specs=P("data"), check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):

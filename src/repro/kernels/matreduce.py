@@ -1,72 +1,51 @@
 """Fused masked-reduce Pallas kernels for counting-plan contractions.
 
-Two primitives live here:
-
 ``matreduce``    total = Σ_{i,j} mask[i,j] · (lhs @ rhsᵀ)[i,j] — the final
                  contraction step of a counting plan (e.g. triangle count
                  = Σ A ⊙ (A@A)); fusing the reduction keeps the (M,N)
-                 product entirely in VMEM, never materialised to HBM.
+                 product entirely in VMEM, never materialised to HBM.  The
+                 scalar accumulates in SMEM.
 
 ``prod_reduce``  the k-factor masked product-reduce behind the compiler's
                  ``CutJoin`` op: Σ_{x,y} [x≠y] · Π_i F_i[x,y] over stacked
                  2-D factor tensors (|cut| = 2), or Σ_x Π_i F_i[x] for 1-D
-                 factors (|cut| = 1, no mask needed — a single cut vertex
-                 is always injective).  The off-diagonal injectivity mask
-                 is derived *in-kernel* from tile indices (broadcasted
-                 iotas offset by the grid position), so no O(n²) mask is
-                 ever built.  Each 2-D grid tile writes a row of per-
-                 column f32 partials (each accumulating bm cells; 1-D
-                 chunks write one bn-cell scalar); the host reduces the
-                 partials in f64, so integer counts stay exact as long as
-                 every chunk partial fits f32's 2^24 integer range —
-                 ``exact_block`` picks the chunk size that provably does.
+                 factors (|cut| = 1, laid out as 128-wide lane rows, no
+                 mask — a single cut vertex is always injective).  The
+                 off-diagonal injectivity mask is derived *in-kernel* from
+                 tile iotas, so no O(n²) mask is ever built.
 
-``tri_reduce``   the |cut| = 3 tier: Σ_{x≠y, y≠z, x≠z} Π_i F_i over a
-                 3-D tile grid, where each factor touches a *subset* of
-                 the three cut axes — (n,) vectors, (n, n) pair tensors
-                 (the common case: an axis-subset decomposition factor
-                 spans only the cut vertices its subpattern contains),
-                 or full (n, n, n) tensors.  Factors are stored with
-                 size-1 broadcast dims on the axes they miss (a free
-                 reshape — nothing is expanded in HBM) and broadcast
-                 per (bm, bn, bk) tile inside the kernel; the pairwise-
-                 distinct mask comes from three broadcasted tile iotas,
-                 so no O(n³) mask is ever materialised.  Each grid tile
-                 writes a (bm, bn) sheet of f32 partials, each
-                 accumulating bk cells — the same chunk-size bound
-                 ``exact_block`` certifies — and the host reduces the
-                 (M, N, gk) partial tensor in f64.
+``prod_reduce_keep``  the keep-axis variant behind ``LocalCount`` plans:
+                 out[x] = Σ_{y≠x} Π_i F_i[x, y].  The same kernel; the
+                 kept axis rides the lanes and partials are summed per
+                 column.
 
-``tri_reduce_keep``  the keep-axis |cut| = 3 variant behind 3-cut
-                 ``LocalCount`` plans: out[x] = Σ_{y,z} [distinct] ·
-                 Π_i F_i — the factors are transposed host-side so the
-                 kept axis leads, then the same kernel runs and the host
-                 reduces the non-kept partial axes per row in f64.
+``tri_reduce[_keep]``  the |cut| = 3 tier: Σ_{x≠y, y≠z, x≠z} Π_i F_i over a
+                 3-D tile grid, where each factor spans a *subset* of the
+                 cut axes and is stored at its own rank (``tri_layout``),
+                 broadcast per tile in VMEM, never expanded in HBM; the
+                 pairwise-distinct mask comes from three tile iotas.  The
+                 keep variant permutes the factors so the kept axis leads
+                 and sums partials per x.
 
-``prod_reduce_keep``  the keep-axis variant behind ``LocalCount`` plans
-                 (the partial-embedding API): out[x] = Σ_{y≠x} Π_i
-                 F_i[x, y] — the same masked product but with one cut
-                 axis *kept* as the output, reducing only the other.
-                 Each grid tile writes its (bm,) per-row f32 partials
-                 (each accumulating bn cells, the same bound
-                 ``exact_block`` certifies for ``prod_reduce``); the
-                 host sums the column-tile partials per row in f64.
-                 ``keep=1`` transposes the factors host-side and runs
-                 the same kernel.
-
-Both primitives zero-pad their inputs up to the tile multiple, so any
-``n`` works; padding is value-preserving because padded mask / factor
-entries are zero and the reduction is a sum.
+**Tiles and exactness.**  Compiled for TPU the tiles are (8, 128)-aligned
+with 128 lanes (``ops._tile``); interpreted they grow to 1024, where
+per-step dispatch dominates.  The certified exactness chunk ``block``
+(``exact_block``: a power of two from 8 up) never sets the tile: each
+tile reduces its product over ``chunk`` sublane rows at a time into
+lane-dense f32 partials, so every partial sums at most ``block`` cells
+and stays an exact integer below 2^24.  Partials are reduced in f64 on
+device; callers hold ``jax.enable_x64``.  Inputs are zero-padded to the
+tile multiple, so any ``n`` works (padded entries are zero and the
+reduction is a sum).
 
 **Global index offsets.**  Every masked kernel takes a small int32
-``offsets`` vector (one entry per grid axis, default zeros) that is
-added to the tile iotas before the injectivity comparison: a caller
-holding only a *slice* of the factor tensors — one device's block of
-cut axis 0 under the mesh tier (``distributed/cutjoin.py``) — passes
-its global start index so ``rows == cols`` still compares global cut
-vertices, not slice-local positions.  Offsets ride as a tiny array
-input (they may be traced values, e.g. ``axis_index * block`` inside
-``shard_map``), replicated to every tile by its BlockSpec.
+``offsets`` vector (one entry per cut axis, default zeros), prefetched
+into SMEM and added to the tile iotas before the injectivity
+comparison: a caller holding only a *slice* of the factor tensors — one
+device's block of a cut axis under the mesh tier
+(``distributed/cutjoin.py``) — passes its global start index so the
+mask compares global cut vertices, not slice-local positions.  Offsets
+may be traced values (``axis_index * rows`` inside ``shard_map``).
 """
 from __future__ import annotations
 
@@ -133,110 +112,105 @@ def matreduce(lhs, rhs, mask, *, bm: int = 128, bn: int = 128,
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
         interpret=interpret,
     )(lhs, rhs, mask)
     return out[0, 0]
 
 
-# -- prod_reduce: Σ over (injective) index tuples of Π_i F_i ----------------------
+# -- join kernels: Σ over (injective) index tuples of Π_i F_i ----------------------
 
-def _pairjoin_kernel(stack_ref, off_ref, out_ref, *, nf, masked, bm, bn):
-    """One (bm, bn) tile of Σ [x≠y] · Π_i F_i[x, y]: product over the
-    factor axis, injectivity mask from tile indices (offset to global
-    coordinates), one row of per-column f32 partials (each bounded by
-    max|Π F| · bm — finer chunks than a per-tile scalar, so large tiles
-    stay exact on integers)."""
+LANE, SUBLANE = 128, 8                 # the TPU's f32 vreg tile is (8, 128)
+SLAB_PARTIALS = 1 << 24                # f32 partials one tri-join slab may hold
+
+
+def _ceil_to(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _pow2_ceil(x: int) -> int:
+    return 1 << max(int(x) - 1, 0).bit_length()
+
+
+def _chunk(block: int, tile: int, extent: int) -> int:
+    """Cells per f32 partial: the certified ``block``, clamped to the
+    tile and to the power-of-two cover of the axis it chunks (fewer
+    cells per partial is always at least as exact), never below one
+    sublane group so row tiles stay (8, 128)-aligned."""
+    assert block >= SUBLANE and tile >= SUBLANE, (block, tile)
+    return min(block, tile, max(SUBLANE, _pow2_ceil(extent)))
+
+
+def _pair_tiles(M: int, N: int, block: int, tile: int):
+    """(row tile, lane tile, chunk) for an (M, N) pair join: rows are a
+    multiple of the chunk, lanes a multiple of 128."""
+    c = _chunk(block, tile, M)
+    return min(tile, _ceil_to(M, c)), min(tile, _ceil_to(N, LANE)), c
+
+
+def _tri_tiles(n: int, block: int, tile: int):
+    """(bx, by, bz, chunk) for an n-cube tri join: z on the lanes, y
+    chunked on the sublanes, x a short leading axis — an (8, 128, 128)
+    f32 tile is 512 KiB of VMEM per temporary at the TPU tile."""
+    c = _chunk(block, tile, n)
+    bx = min(max(SUBLANE, tile // 16), _ceil_to(n, SUBLANE))
+    return bx, min(tile, _ceil_to(n, c)), min(tile, _ceil_to(n, LANE)), c
+
+
+_I0 = np.int32(0)             # index-map zero: stays i32 when traced under x64
+
+
+def _check_x64():
+    # the partial reduce is f64; traced without x64 it would silently
+    # run in f32 and lose exactness
+    assert jax.config.jax_enable_x64, "join kernels reduce under enable_x64"
+
+
+def _pairjoin_kernel(off_ref, stack_ref, out_ref, *, nf, masked, chunk):
+    """One (tr, tc) tile of Π_i F_i[x, y], off-diagonal masked (tile
+    iotas offset to global coordinates by the SMEM ``off_ref``), reduced
+    to (tr / chunk, tc) per-column f32 partials of ``chunk`` cells each."""
     i, j = pl.program_id(0), pl.program_id(1)
-    prod = stack_ref[0, ...]
+    prod = stack_ref[0]
     for f in range(1, nf):
-        prod = prod * stack_ref[f, ...]
+        prod = prod * stack_ref[f]
+    tr, tc = prod.shape
     if masked:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0) \
-            + i * bm + off_ref[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) \
-            + j * bn + off_ref[1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (tr, tc), 0) \
+            + (i * tr + off_ref[0])
+        cols = jax.lax.broadcasted_iota(jnp.int32, (tr, tc), 1) \
+            + (j * tc + off_ref[1])
         prod = jnp.where(rows == cols, jnp.float32(0.0), prod)
-    out_ref[0, :] = jnp.sum(prod, axis=0)
+    out_ref[...] = prod.reshape(tr // chunk, chunk, tc).sum(axis=1)
 
 
-def _vecjoin_kernel(stack_ref, out_ref, *, nf):
-    """One bn-wide chunk of Σ_x Π_i F_i[x] (the |cut| = 1 fast path)."""
-    prod = stack_ref[0, ...]
-    for f in range(1, nf):
-        prod = prod * stack_ref[f, ...]
-    out_ref[0, 0] = jnp.sum(prod)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("distinct", "bm", "bn", "interpret"))
-def _pairjoin_tiles(stack, offsets, *, distinct, bm, bn, interpret):
+@functools.partial(jax.jit, static_argnames=("distinct", "keep", "chunk",
+                                             "tr", "tc", "interpret"))
+def _pairjoin(stack, offsets, *, distinct, keep, chunk, tr, tc, interpret):
+    """(k, M, N) tile-padded f32 stack -> the f64 sum of its (masked)
+    factor product: a scalar, or with ``keep`` the (N,) per-column
+    vector.  Per-tile partials come out lane-dense, (grid0, tr/chunk,
+    N), and are reduced on device in f64."""
+    _check_x64()
     k, M, N = stack.shape
-    grid = (M // bm, N // bn)
-    kern = functools.partial(_pairjoin_kernel, nf=k, masked=distinct,
-                             bm=bm, bn=bn)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((k, bm, bn), lambda i, j: (0, i, j)),
-                  pl.BlockSpec((2,), lambda i, j: (0,))],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], N), jnp.float32),
+    grid = (M // tr, N // tc)
+    R = tr // chunk
+    parts = pl.pallas_call(
+        functools.partial(_pairjoin_kernel, nf=k, masked=distinct,
+                          chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[pl.BlockSpec((k, tr, tc),
+                                   lambda i, j, off: (_I0, i, j))],
+            out_specs=pl.BlockSpec((None, R, tc),
+                                   lambda i, j, off: (i, _I0, j))),
+        out_shape=jax.ShapeDtypeStruct((grid[0], R, N), jnp.float32),
         interpret=interpret,
-    )(stack, offsets)
-
-
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def _vecjoin_tiles(stack, *, bn, interpret):
-    k, N = stack.shape
-    grid = (N // bn,)
-    return pl.pallas_call(
-        functools.partial(_vecjoin_kernel, nf=k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((k, bn), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, grid[0]), jnp.float32),
-        interpret=interpret,
-    )(stack)
-
-
-def _pairjoin_keep_kernel(stack_ref, off_ref, out_ref, *, nf, masked,
-                          bm, bn):
-    """One (bm, bn) tile of the keep-axis join: per-row partials
-    out[x] = Σ_y [x≠y] · Π_i F_i[x, y] over this tile's columns.  Each
-    partial accumulates bn cells — the same chunk bound ``exact_block``
-    certifies — and the host reduces the per-tile rows in f64."""
-    i, j = pl.program_id(0), pl.program_id(1)
-    prod = stack_ref[0, ...]
-    for f in range(1, nf):
-        prod = prod * stack_ref[f, ...]
-    if masked:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0) \
-            + i * bm + off_ref[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) \
-            + j * bn + off_ref[1]
-        prod = jnp.where(rows == cols, jnp.float32(0.0), prod)
-    out_ref[:, 0] = jnp.sum(prod, axis=1)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("distinct", "bm", "bn", "interpret"))
-def _pairjoin_keep_tiles(stack, offsets, *, distinct, bm, bn, interpret):
-    k, M, N = stack.shape
-    grid = (M // bm, N // bn)
-    kern = functools.partial(_pairjoin_keep_kernel, nf=k, masked=distinct,
-                             bm=bm, bn=bn)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((k, bm, bn), lambda i, j: (0, i, j)),
-                  pl.BlockSpec((2,), lambda i, j: (0,))],
-        out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, grid[1]), jnp.float32),
-        interpret=interpret,
-    )(stack, offsets)
+    )(offsets, stack)
+    parts = parts.astype(jnp.float64)
+    return parts.sum(axis=(0, 1)) if keep else parts.sum()
 
 
 def _offsets_or_zero(offsets, naxes: int):
@@ -250,116 +224,170 @@ def _offsets_or_zero(offsets, naxes: int):
     return off
 
 
+def _pair_stack(factors):
+    """f32 (k, M, N) stack; (n,) vectors (|cut| = 1) are laid out as
+    (k, n/128, 128) lane rows so one kernel serves both cut sizes."""
+    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
+    if stack.ndim == 2:
+        stack = _pad_to(stack, (1, LANE)).reshape(stack.shape[0], -1, LANE)
+    assert stack.ndim == 3        # rectangular slices legal (sharded rows)
+    return stack
+
+
+def prod_reduce(factors, *, distinct: bool = True, block: int = 128,
+                tile: int = 128, interpret: bool = False,
+                offsets=None) -> float:
+    """Σ over index tuples of Π_i F_i, factors all (n,) or all (n, n).
+
+    ``distinct`` (2-D only) restricts the sum to off-diagonal cells —
+    the |cut| = 2 injectivity constraint — via an in-kernel tile-index
+    mask; nothing O(n²) is ever materialised besides the factor tensors
+    the caller already holds.  Factors are cast to f32 and zero-padded to
+    the tile multiple; every f32 partial sums at most ``block`` cells and
+    the partials are reduced on device in f64 — exact for integer-valued
+    factors while each partial stays below 2^24, which ``exact_block``
+    certifies.  ``tile`` is the lane width (128 on TPU); ``offsets``
+    gives the factors' global start index per cut axis (sliced callers
+    only; vectors have no mask, so they ignore them).
+    """
+    stack = _pair_stack(factors)
+    distinct = distinct and np.ndim(factors[0]) == 2
+    tr, tc, c = _pair_tiles(stack.shape[1], stack.shape[2], block, tile)
+    stack = _pad_to(stack, (1, tr, tc))
+    with jax.enable_x64(True):
+        return float(_pairjoin(stack, _offsets_or_zero(offsets, 2),
+                               distinct=distinct, keep=False, chunk=c,
+                               tr=tr, tc=tc, interpret=interpret))
+
+
 def prod_reduce_keep(factors, *, keep: int = 0, distinct: bool = True,
-                     bm: int = 128, bn: int = 128,
-                     interpret: bool = False,
-                     offsets=None) -> np.ndarray:
+                     block: int = 128, tile: int = 128,
+                     interpret: bool = False, offsets=None) -> np.ndarray:
     """Keep-axis masked product-reduce over (n, n) factors:
 
         keep=0:  out[x] = Σ_y [x≠y] · Π_i F_i[x, y]
         keep=1:  out[y] = Σ_x [x≠y] · Π_i F_i[x, y]
 
     The anchored partial-embedding read off a |cut| = 2 decomposition
-    join: one cut axis survives as the output vector, the other is
-    reduced in-kernel under the same tile-index injectivity mask as
-    ``prod_reduce`` — still nothing O(n²) materialised beyond the factor
-    tensors the caller already holds.  Factors are cast to f32 and
-    zero-padded to the tile multiple (padding adds zero cells to real
-    rows and zero rows beyond n, both harmless); per-tile f32 row
-    partials are summed across column tiles on the host in f64 — exact
-    for integer factors while each bn-cell partial stays below 2^24,
-    which ``exact_block`` certifies (the guard is identical: both
-    kernels chunk the same per-partial cell count).  ``offsets`` gives
-    the factors' global start index per *original* cut axis (sliced
-    callers only — see the module docstring); the swap below reorders
-    it alongside the axes.
+    join: the ``prod_reduce`` kernel with its per-column partials summed
+    per column, so the kept axis rides the lanes (keep=0 transposes the
+    factors host-side).  Same padding, mask and exactness contract as
+    ``prod_reduce``; ``offsets`` gives the factors' global start index
+    per *original* cut axis, reordered alongside the axes.
     """
-    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
-    assert stack.ndim == 3        # rectangular slices legal (sharded rows)
+    stack = _pair_stack(factors)
     assert keep in (0, 1)
     off = _offsets_or_zero(offsets, 2)
-    if keep == 1:
-        stack = jnp.swapaxes(stack, 1, 2)    # same kernel, rows <-> cols
+    if keep == 0:
+        stack = jnp.swapaxes(stack, 1, 2)    # kept axis onto the lanes
         off = off[::-1]
-    n = stack.shape[1]
-    b = min(bm, bn, max(min(n, stack.shape[2]), 1))
-    stack = _pad_to(stack, (1, b, b))
-    tiles = _pairjoin_keep_tiles(stack, off, distinct=distinct, bm=b, bn=b,
-                                 interpret=interpret)
-    return np.asarray(tiles, np.float64).sum(axis=1)[:n]
+    n = stack.shape[2]
+    tr, tc, c = _pair_tiles(stack.shape[1], n, block, tile)
+    stack = _pad_to(stack, (1, tr, tc))
+    with jax.enable_x64(True):
+        out = _pairjoin(stack, off, distinct=distinct, keep=True, chunk=c,
+                        tr=tr, tc=tc, interpret=interpret)
+    return np.asarray(out, np.float64)[:n]
 
 
 # -- tri_reduce: the |cut| = 3 tiled tri-join --------------------------------------
 
-def _trijoin_kernel(*refs, nf, masked, bm, bn, bk):
-    """One (bm, bn, bk) tile of Σ [x,y,z pairwise distinct] · Π_i F_i.
-    Factor tiles carry size-1 dims on absent axes and broadcast against
-    the full tile shape (never expanded in memory); the pairwise-
-    distinct mask is three tile-iota comparisons, each offset to global
-    coordinates.  The tile writes a (bm, bn) sheet of f32 partials,
-    each accumulating bk cells — the chunk bound ``exact_block``
-    certifies."""
+def tri_layout(axes) -> tuple:
+    """The stored dims of a tri-join factor spanning cut ``axes``: the
+    cut axis each HBM dim carries, None for a unit dim.  Factors keep
+    their own rank (a pair factor stays (n, n) — a trailing unit dim
+    would be padded to a full 128-lane tile in HBM); vectors are stored
+    2-D with the unit dim placed so their block stays (8, 128)-legal."""
+    ax = tuple(axes)
+    return {(0,): (0, None), (1,): (1, None), (2,): (None, 2)}.get(ax, ax)
+
+
+def _trijoin_kernel(meta_ref, *refs, present, masked, chunk):
+    """One (bx, by, bz) tile of [x,y,z pairwise distinct] · Π_i F_i.
+    Each factor block is viewed 3-D with unit dims on its absent axes
+    and broadcast against the tile (never expanded in memory); the mask
+    is three tile-iota comparisons offset to global coordinates by the
+    SMEM ``meta_ref`` (per-axis offsets, then the slab's first x tile).
+    The tile writes (bx, by / chunk, bz) lane-dense f32 partials of
+    ``chunk`` cells each."""
     out_ref = refs[-1]
-    off_ref = refs[-2]
-    prod = refs[0][...]
-    for f in range(1, nf):
-        prod = prod * refs[f][...]
+    bx, R, bz = out_ref.shape
+    shape = (bx, R * chunk, bz)
+    prod = None
+    for ref, ax in zip(refs[:-1], present):
+        f = ref[...].reshape(tuple(d if a in ax else 1
+                                   for a, d in enumerate(shape)))
+        prod = f if prod is None else prod * f
+    prod = jnp.broadcast_to(prod, shape)
     if masked:
         i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        shape = (bm, bn, bk)
-        x = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + i * bm \
-            + off_ref[0]
-        y = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + j * bn \
-            + off_ref[1]
-        z = jax.lax.broadcasted_iota(jnp.int32, shape, 2) + k * bk \
-            + off_ref[2]
-        bad = (x == y) | (x == z) | (y == z)
-        prod = jnp.where(bad, jnp.float32(0.0), prod)
-    else:
-        prod = jnp.broadcast_to(prod, (bm, bn, bk))
-    out_ref[:, :, 0] = jnp.sum(prod, axis=2)
+        x = jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+            + ((meta_ref[3] + i) * bx + meta_ref[0])
+        y = jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+            + (j * shape[1] + meta_ref[1])
+        z = jax.lax.broadcasted_iota(jnp.int32, shape, 2) \
+            + (k * bz + meta_ref[2])
+        prod = jnp.where((x == y) | (x == z) | (y == z), jnp.float32(0.0),
+                         prod)
+    out_ref[...] = prod.reshape(bx, R, chunk, bz).sum(axis=2)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("present", "distinct", "bm", "bn",
-                                    "bk", "interpret"))
-def _trijoin_tiles(*stack, offsets, present, distinct, bm, bn, bk,
-                   interpret):
-    """``stack``: one 3-D array per factor, shape (M|1, N|1, K|1) with
-    size-1 dims on the axes ``present[f]`` misses.  Returns the (M, N,
-    gk) f32 partial tensor (gk = K // bk column-tile partials)."""
-    M = max(s.shape[0] for s in stack)
-    N = max(s.shape[1] for s in stack)
-    K = max(s.shape[2] for s in stack)
-    grid = (M // bm, N // bn, K // bk)
+                   static_argnames=("present", "distinct", "keep", "chunk",
+                                    "bx", "by", "bz", "interpret"))
+def _trijoin(*stack, offsets, present, distinct, keep, chunk, bx, by, bz,
+             interpret):
+    """``stack``: one tile-padded f32 array per factor, stored as
+    ``tri_layout(present[f])`` says.  Returns the f64 sum: a scalar, or
+    with ``keep`` the per-x vector.  x runs in slabs (``lax.map``), each
+    slab's partials reduced on device in f64 before the next, so the
+    partial buffer stays near ``SLAB_PARTIALS`` whatever n is."""
+    _check_x64()
+    extent = [1, 1, 1]
+    for s, ax in zip(stack, present):
+        for d, a in zip(s.shape, tri_layout(ax)):
+            if a is not None:
+                extent[a] = max(extent[a], d)
+    M, N, K = extent
+    tiles = (bx, by, bz)
+    R = by // chunk
+    slab = bx
+    while M % (2 * slab) == 0 and 2 * slab * N * K // chunk <= SLAB_PARTIALS:
+        slab *= 2
 
     def spec(axes):
-        block = (bm if 0 in axes else 1, bn if 1 in axes else 1,
-                 bk if 2 in axes else 1)
+        dims = tri_layout(axes)
         return pl.BlockSpec(
-            block, lambda i, j, k, axes=axes: (i if 0 in axes else 0,
-                                               j if 1 in axes else 0,
-                                               k if 2 in axes else 0))
+            tuple(1 if a is None else tiles[a] for a in dims),
+            lambda i, j, k, meta, dims=dims: tuple(
+                _I0 if a is None else (meta[3] + i, j, k)[a] for a in dims))
 
-    kern = functools.partial(_trijoin_kernel, nf=len(stack),
-                             masked=distinct, bm=bm, bn=bn, bk=bk)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[spec(axes) for axes in present] +
-                 [pl.BlockSpec((3,), lambda i, j, k: (0,))],
-        out_specs=pl.BlockSpec((bm, bn, 1), lambda i, j, k: (i, j, k)),
-        out_shape=jax.ShapeDtypeStruct((M, N, grid[2]), jnp.float32),
-        interpret=interpret,
-    )(*stack, offsets)
+    call = pl.pallas_call(
+        functools.partial(_trijoin_kernel, present=present, masked=distinct,
+                          chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(slab // bx, N // by, K // bz),
+            in_specs=[spec(axes) for axes in present],
+            out_specs=pl.BlockSpec((bx, None, R, bz),
+                                   lambda i, j, k, meta: (i, j, _I0, k))),
+        out_shape=jax.ShapeDtypeStruct((slab, N // by, R, K), jnp.float32),
+        interpret=interpret)
+
+    def one_slab(s):
+        meta = jnp.concatenate([offsets, (s * (slab // bx))[None]])
+        parts = call(meta.astype(jnp.int32), *stack).astype(jnp.float64)
+        return parts.sum(axis=(1, 2, 3)) if keep else parts.sum()
+
+    out = jax.lax.map(one_slab, jnp.arange(M // slab, dtype=jnp.int32))
+    return out.reshape(-1) if keep else out.sum()
 
 
-def _tri_normalise(factors, axes, n: int, b: int):
-    """Cast each factor to f32, reshape to 3-D with size-1 dims on its
-    absent axes (a free view — axis-subset factors are broadcast per
-    tile, never expanded), zero-pad present axes to the tile multiple,
-    and inject a ones-vector on any axis no factor covers (zero-padded,
-    so padding never contributes even on uncovered axes)."""
+def _tri_normalise(factors, axes, n: int, tiles):
+    """Cast each factor to f32, store it as ``tri_layout`` says (its own
+    rank — axis-subset factors are broadcast per tile, never expanded),
+    zero-pad each stored axis to its tile multiple, and inject a
+    ones-vector on any axis no factor covers (zero-padded, so padding
+    never contributes even on uncovered axes)."""
     covered = set()
     stacked, present = [], []
     for F, ax in zip(factors, axes):
@@ -369,22 +397,23 @@ def _tri_normalise(factors, axes, n: int, b: int):
         assert F.ndim == len(ax) and all(s == n for s in F.shape), \
             (F.shape, ax, n)
         covered |= set(ax)
-        shape = tuple(n if a in ax else 1 for a in range(3))
-        F = F.reshape(shape)
-        F = _pad_to(F, tuple(b if a in ax else 1 for a in range(3)))
         stacked.append(F)
         present.append(ax)
     for a in sorted({0, 1, 2} - covered):
-        ones = _pad_to(jnp.ones((n,), jnp.float32), (b,))
-        shape = tuple(-1 if x == a else 1 for x in range(3))
-        stacked.append(ones.reshape(shape))
+        stacked.append(jnp.ones((n,), jnp.float32))
         present.append((a,))
-    return stacked, tuple(present)
+    out = []
+    for F, ax in zip(stacked, present):
+        dims = tri_layout(ax)
+        F = F.reshape(tuple(1 if a is None else n for a in dims))
+        out.append(_pad_to(F, tuple(1 if a is None else tiles[a]
+                                    for a in dims)))
+    return out, tuple(present)
 
 
 def tri_reduce(factors, axes, *, n: int, distinct: bool = True,
-               bm: int = 128, bn: int = 128, bk: int = 128,
-               interpret: bool = False, offsets=None) -> float:
+               block: int = 128, tile: int = 128, interpret: bool = False,
+               offsets=None) -> float:
     """Σ over (pairwise-distinct) index triples of Π_i F_i, where factor
     i spans only the cut axes ``axes[i]`` (a sorted subset of (0, 1, 2))
     and broadcasts along the rest.
@@ -392,36 +421,28 @@ def tri_reduce(factors, axes, *, n: int, distinct: bool = True,
     The |cut| = 3 decomposition join.  The injectivity mask is derived
     in-kernel from tile indices — nothing O(n³) is materialised beyond
     whatever genuinely 3-D factors the caller already holds; axis-subset
-    factors stay at their own size.  Per-tile (bm, bn) f32 partials each
-    accumulate bk cells, so ``exact_block`` certifies the same chunk
-    bound as the pair tier with b = bk; the host reduces the partial
-    tensor in f64.  ``offsets`` gives the factors' global start index
-    per cut axis (sliced callers only)."""
-    b = min(bm, bn, bk, max(n, 1))
-    stacked, present = _tri_normalise(factors, axes, n, b)
-    tiles = _trijoin_tiles(*stacked, offsets=_offsets_or_zero(offsets, 3),
-                           present=present, distinct=distinct,
-                           bm=b, bn=b, bk=b, interpret=interpret)
-    return float(np.asarray(tiles, np.float64).sum())
+    factors stay at their own size.  Every f32 partial sums at most
+    ``block`` cells, so ``exact_block`` certifies the same chunk bound
+    as the pair tier; partials are reduced on device in f64.
+    ``offsets`` gives the factors' global start index per cut axis
+    (sliced callers only)."""
+    bx, by, bz, c = _tri_tiles(n, block, tile)
+    stacked, present = _tri_normalise(factors, axes, n, (bx, by, bz))
+    with jax.enable_x64(True):
+        return float(_trijoin(*stacked, offsets=_offsets_or_zero(offsets, 3),
+                              present=present, distinct=distinct,
+                              keep=False, chunk=c, bx=bx, by=by, bz=bz,
+                              interpret=interpret))
 
 
-def tri_reduce_keep(factors, axes, *, keep: int, n: int,
-                    distinct: bool = True, bm: int = 128, bn: int = 128,
-                    bk: int = 128, interpret: bool = False,
-                    offsets=None) -> np.ndarray:
-    """Keep-axis tri-join: out[w] = Σ over the other two (pairwise-
-    distinct) axes of Π_i F_i — the anchored partial-embedding vector of
-    a |cut| = 3 plan.  ``keep`` picks the surviving axis; factors are
-    transposed host-side so it leads (free for axis-subset factors —
-    only their axis labels move), then the same kernel runs and the
-    host sums the non-kept partial axes per row in f64.  ``offsets``
-    gives the factors' global start index per *original* cut axis; the
-    permutation below reorders it alongside the axes."""
+def tri_permute(factors, axes, keep: int):
+    """Permute factors so cut axis ``keep`` leads (free for axis-subset
+    factors — only their axis labels move).  Returns (factors, axes,
+    perm), ``perm[i]`` the original axis now at position i."""
     assert keep in (0, 1, 2)
     perm = (keep,) + tuple(a for a in range(3) if a != keep)
     rank = {a: i for i, a in enumerate(perm)}
-    paxes = []
-    pfactors = []
+    pfactors, paxes = [], []
     for F, ax in zip(factors, axes):
         ax = tuple(ax)
         new = tuple(sorted(rank[a] for a in ax))
@@ -429,13 +450,28 @@ def tri_reduce_keep(factors, axes, *, keep: int, n: int,
         pfactors.append(np.transpose(np.asarray(F), order)
                         if order != tuple(range(len(ax))) else F)
         paxes.append(new)
+    return pfactors, paxes, perm
+
+
+def tri_reduce_keep(factors, axes, *, keep: int, n: int,
+                    distinct: bool = True, block: int = 128,
+                    tile: int = 128, interpret: bool = False,
+                    offsets=None) -> np.ndarray:
+    """Keep-axis tri-join: out[w] = Σ over the other two (pairwise-
+    distinct) axes of Π_i F_i — the anchored partial-embedding vector of
+    a |cut| = 3 plan.  ``keep`` picks the surviving axis; factors are
+    permuted host-side so it leads, then the same kernel runs with its
+    partials reduced per x row.  ``offsets`` gives the factors' global
+    start index per *original* cut axis, permuted alongside the axes."""
+    pfactors, paxes, perm = tri_permute(factors, axes, keep)
     off = _offsets_or_zero(offsets, 3)[jnp.asarray(perm)]
-    b = min(bm, bn, bk, max(n, 1))
-    stacked, present = _tri_normalise(pfactors, paxes, n, b)
-    tiles = _trijoin_tiles(*stacked, offsets=off, present=present,
-                           distinct=distinct, bm=b, bn=b, bk=b,
-                           interpret=interpret)
-    return np.asarray(tiles, np.float64).sum(axis=(1, 2))[:n]
+    bx, by, bz, c = _tri_tiles(n, block, tile)
+    stacked, present = _tri_normalise(pfactors, paxes, n, (bx, by, bz))
+    with jax.enable_x64(True):
+        out = _trijoin(*stacked, offsets=off, present=present,
+                       distinct=distinct, keep=True, chunk=c, bx=bx, by=by,
+                       bz=bz, interpret=interpret)
+    return np.asarray(out, np.float64)[:n]
 
 
 EXACT_LIMIT = float(1 << 24)                 # f32 exact-integer range
@@ -444,16 +480,15 @@ EXACT_LIMIT = float(1 << 24)                 # f32 exact-integer range
 def exact_block(factors, max_block: int = 1024, min_block: int = 8,
                 maxes=None):
     """Largest power-of-two chunk size whose f32 partial sums stay exact
-    for integer-valued ``factors``.  A chunk accumulates ``b`` cells
-    (per-column partials of a (b, bn) tile for 2-D factors, one bn-wide
-    scalar for 1-D, the bk depth of one (bm, bn) partial sheet for the
-    tri tier), so every partial is an integer bounded by
-    (Π_i max|F_i|) · b, and integers up to 2^24 are exactly
-    representable in f32.  ``maxes`` supplies precomputed per-factor max
-    magnitudes (serving plans cache them — see ``CompiledPlan``) so
-    repeated executions skip the full-tensor scan.  Returns None when
-    even a ``min_block`` chunk cannot guarantee exactness — callers
-    should take an f64 path instead."""
+    for integer-valued ``factors``.  Every kernel partial accumulates at
+    most ``b`` cells (a column chunk of a pair tile, a y chunk of a tri
+    tile), so every partial is an integer bounded by (Π_i max|F_i|) · b,
+    and integers up to 2^24 are exactly representable in f32.  ``maxes``
+    supplies precomputed per-factor max magnitudes (serving plans cache
+    them — see ``CompiledPlan``) so repeated executions skip the
+    full-tensor scan.  Returns None when even a ``min_block`` chunk
+    cannot guarantee exactness — callers should take an f64 path
+    instead."""
     maxprod = 1.0
     if maxes is None:
         maxes = [float(np.abs(np.asarray(F)).max()) for F in factors]
@@ -465,38 +500,3 @@ def exact_block(factors, max_block: int = 1024, min_block: int = 8,
             return b
         b //= 2
     return None
-
-
-def prod_reduce(factors, *, distinct: bool = True, bm: int = 128,
-                bn: int = 128, interpret: bool = False,
-                offsets=None) -> float:
-    """Σ over index tuples of Π_i F_i, factors all (n,) or all (n, n).
-
-    ``distinct`` (2-D only) restricts the sum to off-diagonal cells —
-    the |cut| = 2 injectivity constraint — via an in-kernel tile-index
-    mask; nothing O(n²) is ever materialised besides the factor tensors
-    the caller already holds.  Factors are cast to f32 and zero-padded to
-    the tile multiple; chunked f32 partials (per-column for 2-D tiles)
-    are reduced on the host in f64 — exact for integer-valued factors
-    while each chunk partial stays below 2^24, which ``exact_block``
-    certifies for a given factor set.  ``offsets`` gives the factors'
-    global start index per cut axis (sliced callers only; the 1-D fast
-    path has no mask, so it ignores them).
-    """
-    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
-    if stack.ndim == 2:                      # |cut| = 1: vector fast path
-        N = stack.shape[1]
-        stack = _pad_to(stack, (1, min(bn, max(N, 1))))
-        tiles = _vecjoin_tiles(stack, bn=min(bn, stack.shape[1]),
-                               interpret=interpret)
-    else:
-        # rectangular (m, n) slices are legal: a sharded caller holds one
-        # device's rows of cut axis 0 and passes their global offset
-        assert stack.ndim == 3
-        M, N = stack.shape[1], stack.shape[2]
-        b = min(bm, bn, max(min(M, N), 1))
-        stack = _pad_to(stack, (1, b, b))
-        tiles = _pairjoin_tiles(stack, _offsets_or_zero(offsets, 2),
-                                distinct=distinct, bm=b, bn=b,
-                                interpret=interpret)
-    return float(np.asarray(tiles, np.float64).sum())

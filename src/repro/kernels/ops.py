@@ -62,7 +62,22 @@ def triangle_count(adj, *, interpret=None):
     return masked_matmul_reduce(a, a, a, interpret=interpret) / 6.0
 
 
-def cutjoin_reduce(factors, *, distinct=True, bm=None, bn=None,
+def _tile(interpret: bool, tile=None) -> int:
+    """Join-kernel tile: 128 compiled for TPU (the lane width; VMEM-
+    sized), 1024 interpreted, where per-grid-step dispatch dominates and
+    VMEM is no constraint — fewer, larger tiles keep the CPU validation
+    path faster than the XLA dense-mask join.  The certified chunk
+    (``block``) is applied inside the tile, so it never sets the lane
+    width."""
+    return tile if tile is not None else (1024 if interpret else 128)
+
+
+def _count_call(op: str, cut: int, interpret: bool):
+    obs.counter("kernel.calls", op=op, cut=cut,
+                mode="interpret" if interpret else "compiled")
+
+
+def cutjoin_reduce(factors, *, distinct=True, block=None, tile=None,
                    interpret=None, offsets=None) -> float:
     """The decomposition join Σ_{e_c} Π_i M_i(e_c) as a fused kernel.
 
@@ -70,50 +85,40 @@ def cutjoin_reduce(factors, *, distinct=True, bm=None, bn=None,
     |cut| = 1 (``distinct`` is moot — one vertex is always injective) or
     (n, n) matrices for |cut| = 2, where ``distinct`` applies the
     off-diagonal injectivity mask in-kernel from tile indices.  Arbitrary
-    ``n`` works (zero-padding to the tile multiple); the result is the
-    f64 host-side sum of per-tile f32 partials.  ``offsets`` gives the
-    factors' global start index per cut axis when the caller holds only
-    a slice (the mesh tier — see ``distributed/cutjoin.py``).
-
-    Default tiles: 128 on TPU (MXU-aligned, VMEM-sized) but 1024 in
-    interpret mode, where per-grid-step dispatch dominates and VMEM is
-    not a constraint — fewer, larger chunks keep the CPU validation path
-    faster than the XLA dense-mask join.
+    ``n`` works (zero-padding to the tile multiple).  ``block`` bounds
+    the cells per f32 partial — take it from ``cutjoin_exact_block`` so
+    integer counts stay exact; partials are summed in f64.  ``offsets``
+    gives the factors' global start index per cut axis when the caller
+    holds only a slice (the mesh tier — see ``distributed/cutjoin.py``).
     """
     interpret = _auto_interpret(interpret)
-    if bm is None:
-        bm = 1024 if interpret else 128
-    if bn is None:
-        bn = bm
-    obs.counter("kernel.calls", op="cutjoin_reduce",
-                cut=2 if getattr(factors[0], "ndim", 2) == 2 else 1)
-    return _mr.prod_reduce(factors, distinct=distinct, bm=bm, bn=bn,
-                           interpret=interpret, offsets=offsets)
+    tile = _tile(interpret, tile)
+    _count_call("cutjoin_reduce",
+                2 if getattr(factors[0], "ndim", 2) == 2 else 1, interpret)
+    return _mr.prod_reduce(factors, distinct=distinct, block=block or tile,
+                           tile=tile, interpret=interpret, offsets=offsets)
 
 
-def cutjoin_reduce_keep(factors, *, keep=0, distinct=True, bm=None,
-                        bn=None, interpret=None,
+def cutjoin_reduce_keep(factors, *, keep=0, distinct=True, block=None,
+                        tile=None, interpret=None,
                         offsets=None) -> np.ndarray:
     """Keep-axis decomposition join: out[x] = Σ_{y≠x} Π_i M_i(x, y) over
     (n, n) cut tensors — the anchored partial-embedding vector of a
     |cut| = 2 plan (``keep`` picks which cut axis survives).  Same
     padding, masking, and chunked f32/f64 exactness story as
     ``cutjoin_reduce``; ``cutjoin_exact_block`` certifies the same chunk
-    size for both (each partial accumulates one tile-width of cells).
+    size for both.
     """
     interpret = _auto_interpret(interpret)
-    if bm is None:
-        bm = 1024 if interpret else 128
-    if bn is None:
-        bn = bm
-    obs.counter("kernel.calls", op="cutjoin_reduce_keep", cut=2)
+    tile = _tile(interpret, tile)
+    _count_call("cutjoin_reduce_keep", 2, interpret)
     return _mr.prod_reduce_keep(factors, keep=keep, distinct=distinct,
-                                bm=bm, bn=bn, interpret=interpret,
-                                offsets=offsets)
+                                block=block or tile, tile=tile,
+                                interpret=interpret, offsets=offsets)
 
 
 def cutjoin_reduce3(factors, axes, *, n, distinct=True, block=None,
-                    interpret=None, offsets=None) -> float:
+                    tile=None, interpret=None, offsets=None) -> float:
     """The |cut| = 3 decomposition join Σ_{e_c pairwise distinct} Π_i
     M_i(e_c) as a tiled tri-join kernel.
 
@@ -123,21 +128,19 @@ def cutjoin_reduce3(factors, axes, *, n, distinct=True, block=None,
     — they are never expanded to 3-D — and the pairwise-distinct mask
     is derived from tile iotas, so nothing O(n³) is materialised beyond
     whatever genuinely 3-D factors the caller already holds.  ``block``
-    bounds the per-partial chunk (bk); take it from
+    bounds the cells per f32 partial; take it from
     ``cutjoin_exact_block`` so integer counts stay exact.
     """
     interpret = _auto_interpret(interpret)
-    if block is None:
-        block = 1024 if interpret else 128
-    b = min(block, 128) if not interpret else block
-    obs.counter("kernel.calls", op="cutjoin_reduce3", cut=3)
+    tile = _tile(interpret, tile)
+    _count_call("cutjoin_reduce3", 3, interpret)
     return _mr.tri_reduce(factors, axes, n=n, distinct=distinct,
-                          bm=b, bn=b, bk=b, interpret=interpret,
-                          offsets=offsets)
+                          block=block or tile, tile=tile,
+                          interpret=interpret, offsets=offsets)
 
 
 def cutjoin_reduce3_keep(factors, axes, *, keep, n, distinct=True,
-                         block=None, interpret=None,
+                         block=None, tile=None, interpret=None,
                          offsets=None) -> np.ndarray:
     """Keep-axis |cut| = 3 join: out[w] = Σ over the two non-kept cut
     axes (pairwise-distinct triples only) of Π_i M_i — the anchored
@@ -145,24 +148,22 @@ def cutjoin_reduce3_keep(factors, axes, *, keep, n, distinct=True,
     broadcasting, in-kernel mask, and chunked f32/f64 exactness story
     as ``cutjoin_reduce3``."""
     interpret = _auto_interpret(interpret)
-    if block is None:
-        block = 1024 if interpret else 128
-    b = min(block, 128) if not interpret else block
-    obs.counter("kernel.calls", op="cutjoin_reduce3_keep", cut=3)
+    tile = _tile(interpret, tile)
+    _count_call("cutjoin_reduce3_keep", 3, interpret)
     return _mr.tri_reduce_keep(factors, axes, keep=keep, n=n,
-                               distinct=distinct, bm=b, bn=b, bk=b,
-                               interpret=interpret, offsets=offsets)
+                               distinct=distinct, block=block or tile,
+                               tile=tile, interpret=interpret,
+                               offsets=offsets)
 
 
 def runtime_block(block: int, *, interpret=None) -> int:
     """Clamp a statically certified ``exact_block`` chunk to the running
-    backend's tile cap (the same 1024-interpret / 128-TPU cap
+    backend's tile (the same 1024-interpret / 128-TPU cap
     ``cutjoin_exact_block`` applies).  Certificates are computed against
     the interpret-mode maximum (``analysis.verify.precertify``); a
     smaller chunk is always at least as exact, so clamping preserves the
     guarantee."""
-    cap = 1024 if _auto_interpret(interpret) else 128
-    return min(int(block), cap)
+    return min(int(block), _tile(_auto_interpret(interpret)))
 
 
 def cutjoin_exact_block(factors, *, interpret=None, maxes=None):
@@ -172,7 +173,7 @@ def cutjoin_exact_block(factors, *, interpret=None, maxes=None):
     ``maxes`` passes cached per-factor max magnitudes so serving plans
     skip the device→host factor scan (see ``matreduce.exact_block``).
     """
-    cap = 1024 if _auto_interpret(interpret) else 128
+    cap = _tile(_auto_interpret(interpret))
     block = _mr.exact_block(factors, max_block=cap, maxes=maxes)
     obs.counter("kernel.exact_block",
                 outcome="granted" if block is not None else "refused")

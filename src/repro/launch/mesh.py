@@ -10,19 +10,11 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(num_axes: int) -> dict:
-    """axis_types only where the installed jax has it (>= 0.5); older
-    releases default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=None, axes=None):
@@ -30,4 +22,5 @@ def make_host_mesh(shape=None, axes=None):
     n = len(jax.devices())
     if shape is None:
         shape, axes = (1, n), ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -28,6 +28,7 @@ from repro.core.fsm import fsm
 from repro.core.motifs import motif_patterns
 from repro.core.pattern import chain, pseudo_clique
 from repro.graph import generators as gen
+from repro.launch.jax_cache import setup_compile_cache
 
 
 def build_graph(args):
@@ -92,6 +93,7 @@ def main(argv=None):
                     "grid — results stay bit-for-bit equal to "
                     "single-device)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     mesh = None
     if args.mesh is not None and args.mesh > 1:

@@ -17,16 +17,6 @@ import jax.numpy as jnp
 from repro.distributed.meshes import constrain
 from repro.models.params import P
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                        # jax < 0.5: experimental home,
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs,   # check_vma was check_rep
-                              check_rep=bool(check_vma))
-
 
 def moe_specs(cfg):
     e, d = cfg.moe, cfg.d_model
@@ -242,7 +232,7 @@ def moe_apply_ep(p, x, cfg, mesh):
         wo_spec = P("model", "data", None)      # f rows sharded
     else:
         wo_spec = P("model", None, None)
-    y = _shard_map(
+    y = jax.shard_map(                    # lint: allow=mesh-guard
         body, mesh=mesh,
         in_specs=(xs, P(xs[0], xs[1], None), P(xs[0], xs[1], None),
                   ws, ws, wo_spec),

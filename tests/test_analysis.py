@@ -546,14 +546,14 @@ def test_lint_mutable_default():
 def test_lint_kernel_guard_protocol():
     bad = ("from repro.kernels import ops\n"
            "def join(Ms):\n"
-           "    return ops.cutjoin_reduce(Ms, bm=128, bn=128)\n")
+           "    return ops.cutjoin_reduce(Ms, block=128)\n")
     assert [f.rule for f in _findings(bad)] == ["kernel-guard"]
     ok = ("from repro.kernels import ops\n"
           "def join(Ms):\n"
           "    block = ops.cutjoin_exact_block(Ms)\n"
           "    if block is None:\n"
           "        return None\n"
-          "    return ops.cutjoin_reduce(Ms, bm=block, bn=block)\n")
+          "    return ops.cutjoin_reduce(Ms, block=block)\n")
     assert _findings(ok) == []
     # class scope counts: a guard helper method covers sibling methods
     ok2 = ("from repro.kernels import ops\n"
@@ -562,7 +562,7 @@ def test_lint_kernel_guard_protocol():
            "        return ops.cutjoin_exact_block(Ms)\n"
            "    def join(self, Ms):\n"
            "        b = self.guard(Ms)\n"
-           "        return ops.cutjoin_reduce(Ms, bm=b, bn=b)\n")
+           "        return ops.cutjoin_reduce(Ms, block=b)\n")
     assert _findings(ok2) == []
 
 

@@ -51,6 +51,31 @@ def test_pair_join_never_needs_tile_multiple():
         assert got == ((F * F) * (1.0 - np.eye(n))).sum()
 
 
+@pytest.mark.parametrize("block", [8, 32])
+@pytest.mark.parametrize("op", ["pair", "vec", "keep0", "keep1"])
+def test_certified_block_below_lane_width(op, block):
+    """A certified chunk below 128 runs inside the TPU's 128-lane tiles
+    and still bounds every f32 partial: factor products sit just under
+    2^24 / block, where one partial per 128 cells would round."""
+    m = int((2 ** 24 // block) ** 0.5)        # max |F|: m² · block <= 2^24
+    n = 40_000 if op == "vec" else 200
+    shape = (n,) if op == "vec" else (n, n)
+    Fs = [RNG.integers(0, m + 1, size=shape) for _ in range(2)]
+    for F in Fs:
+        F.flat[0] = m
+    assert ops.cutjoin_exact_block(Fs, interpret=False) == block
+    prod = Fs[0] * Fs[1]                      # exact int64 reference
+    if op != "vec":
+        np.fill_diagonal(prod, 0)
+    kw = dict(block=block, tile=128, interpret=True)
+    if op.startswith("keep"):
+        keep = int(op[-1])
+        got = ops.cutjoin_reduce_keep(Fs, keep=keep, **kw)
+        assert np.array_equal(got, prod.sum(axis=1 - keep))
+    else:
+        assert ops.cutjoin_reduce(Fs, **kw) == prod.sum()
+
+
 # -- golden-value equivalence through the compiler --------------------------------
 
 CUT_PATTERNS = [chain(4), cycle(4), tailed_triangle(), HOUSE, chain(5)]

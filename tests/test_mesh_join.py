@@ -48,18 +48,18 @@ def test_sharded_joins_match_single_device():
             b = ops.cutjoin_exact_block(v); assert b is not None
             assert dcj.sharded_cutjoin(v, mesh=mesh, distinct=False,
                                        block=b) == \\
-                ops.cutjoin_reduce(v, distinct=False, bm=b, bn=b), n
+                ops.cutjoin_reduce(v, distinct=False, block=b), n
 
             Ms = [rng.integers(0, 6, size=(n, n)).astype(np.float64)
                   for _ in range(3)]
             b = ops.cutjoin_exact_block(Ms); assert b is not None
             assert dcj.sharded_cutjoin(Ms, mesh=mesh, block=b) == \\
-                ops.cutjoin_reduce(Ms, bm=b, bn=b), n
+                ops.cutjoin_reduce(Ms, block=b), n
 
             for keep in (0, 1):
                 got = dcj.sharded_cutjoin_keep(Ms, keep=keep, mesh=mesh,
                                                block=b)
-                ref = ops.cutjoin_reduce_keep(Ms, keep=keep, bm=b, bn=b)
+                ref = ops.cutjoin_reduce_keep(Ms, keep=keep, block=b)
                 assert np.array_equal(got, ref), (n, keep)
 
         axes = [(0, 1), (1, 2), (0, 2)]      # axis-subset tri factors
@@ -92,10 +92,48 @@ def test_sharded_joins_match_single_device():
         for n, k in ((33, 2), (17, 3)):
             Ms = [rng.integers(0, 3, size=(n,) * k).astype(np.float64)
                   * big for _ in range(2)]
-            with jax.experimental.enable_x64():
+            with jax.enable_x64():
                 ref = float(jnp.sum(jnp.prod(jnp.stack(
                     [jnp.asarray(M) for M in Ms]), axis=0)))
             assert dcj.sharded_dense_join(Ms, k, mesh=mesh) == ref, (n, k)
+        print("OK")
+    """)
+    assert "OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_sharded_joins_lane_width_tiles():
+    """The sharded routes at the TPU's 128-lane tile with certified
+    chunks below it (8, 32): bit-for-bit against single-device."""
+    r = _run("""
+        import numpy as np
+        from repro.distributed import cutjoin as dcj, meshes
+        from repro.kernels import ops
+
+        mesh = meshes.data_mesh()
+        rng = np.random.default_rng(3)
+        n, n3 = 300, 130
+        Ms = [rng.integers(0, 40, size=(n, n)).astype(np.float64)
+              for _ in range(2)]
+        axes = [(0, 1), (1, 2), (0, 2)]
+        Ts = [rng.integers(0, 6, size=(n3, n3)).astype(np.float64)
+              for _ in axes]
+        for b in (8, 32):
+            kw = dict(block=b, tile=128)
+            assert dcj.sharded_cutjoin(Ms, mesh=mesh, **kw) == \\
+                ops.cutjoin_reduce(Ms, **kw), b
+            assert dcj.sharded_cutjoin3(Ts, axes, n=n3, mesh=mesh, **kw) \\
+                == ops.cutjoin_reduce3(Ts, axes, n=n3, **kw), b
+            for keep in (0, 1):
+                assert np.array_equal(
+                    dcj.sharded_cutjoin_keep(Ms, keep=keep, mesh=mesh,
+                                             **kw),
+                    ops.cutjoin_reduce_keep(Ms, keep=keep, **kw)), (b, keep)
+            for keep in (0, 1, 2):
+                assert np.array_equal(
+                    dcj.sharded_cutjoin3_keep(Ts, axes, keep=keep, n=n3,
+                                              mesh=mesh, **kw),
+                    ops.cutjoin_reduce3_keep(Ts, axes, keep=keep, n=n3,
+                                             **kw)), (b, keep)
         print("OK")
     """)
     assert "OK" in r.stdout, r.stdout + r.stderr
@@ -265,7 +303,7 @@ def test_join_batch_matches_serial():
     stacks = rng.integers(0, 6, size=(11, 2, 48, 48)).astype(np.float64)
     block = min(b for b in (ops.cutjoin_exact_block(list(s))
                             for s in stacks) if b is not None)
-    serial = np.asarray([ops.cutjoin_reduce(list(s), bm=block, bn=block)
+    serial = np.asarray([ops.cutjoin_reduce(list(s), block=block)
                          for s in stacks])
     ex = dcj.MeshExecutor(meshes.data_mesh())
     assert np.array_equal(ex.join_batch(stacks), serial)

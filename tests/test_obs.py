@@ -380,10 +380,11 @@ def test_plancache_clear_keeps_registry_monotonic(tmp_path):
 def test_kernel_call_counters():
     from repro.kernels import ops
     reg = obs.REGISTRY
-    before = reg.get("kernel.calls", op="cutjoin_reduce", cut=2)
+    labels = dict(op="cutjoin_reduce", cut=2, mode="interpret")
+    before = reg.get("kernel.calls", **labels)
     M = np.ones((8, 8))
-    ops.cutjoin_reduce([M, M])
-    assert reg.get("kernel.calls", op="cutjoin_reduce", cut=2) == before + 1
+    ops.cutjoin_reduce([M, M], interpret=True)
+    assert reg.get("kernel.calls", **labels) == before + 1
     granted = reg.get("kernel.exact_block", outcome="granted")
     precertified = reg.get("kernel.exact_block", outcome="precertified")
     assert granted + precertified >= 1

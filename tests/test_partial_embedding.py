@@ -217,7 +217,7 @@ def test_exact_guard_falls_back_to_xla():
     from repro.compiler.lowering import _join_keep
     import jax
     import jax.numpy as jnp
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         got = np.asarray(_join_keep(jnp.stack(
             [jnp.asarray(F) for F in Fs]), 0), np.float64)
     assert np.array_equal(got, want)

@@ -105,6 +105,31 @@ def test_tri_reduce_tile_padding():
         assert got == want, axes
 
 
+@pytest.mark.parametrize("block", [8, 32])
+@pytest.mark.parametrize("keep", [None, 0, 1, 2])
+def test_certified_block_below_lane_width(keep, block):
+    """A certified chunk below 128 runs inside the TPU's (8, 128, 128)
+    tiles and still bounds every f32 partial: factor products sit just
+    under 2^24 / block, where one partial per 128 cells would round."""
+    m = round((2 ** 24 // block) ** (1 / 3))
+    while m ** 3 * block > 2 ** 24:
+        m -= 1
+    n, axes = 130, [(0, 1), (1, 2), (0, 2)]
+    Fs = [RNG.integers(0, m + 1, size=(n, n)).astype(np.float64)
+          for _ in axes]
+    for F in Fs:
+        F[0, 0] = m
+    assert ops.cutjoin_exact_block(Fs, interpret=False) == block
+    dense = _oracle(Fs, axes, n)
+    kw = dict(n=n, block=block, tile=128, interpret=True)
+    if keep is None:
+        assert ops.cutjoin_reduce3(Fs, axes, **kw) == dense.sum()
+    else:
+        got = ops.cutjoin_reduce3_keep(Fs, axes, keep=keep, **kw)
+        want = dense.sum(axis=tuple(a for a in range(3) if a != keep))
+        assert np.array_equal(got, want)
+
+
 # -- golden-value equivalence through the compiler ----------------------------------
 
 TRI_PATTERNS = [K5_MINUS_EDGE, SIX_CYCLE, chain(5), cycle(5),
