@@ -1,16 +1,23 @@
 """Plan-execution tracing and the cost-model drift report.
 
 Walks the observability layer end to end: attach a tracer to a compiled
-plan, read the span tree it records (one span per IR node evaluation,
-nested exactly as the evaluation recursion nests), export it for
-chrome://tracing, and aggregate the (predicted cost, measured time)
-pairs into the calibration report that tells you where the APCT cost
-model drifts from reality.
+plan, run it under the JAX profiler (whose trace holds the program's
+``gpm.*`` spans beside the device's operations, on one clock), read the
+span tree and the counts the tracer records (one span per IR node
+evaluation, nested exactly as the evaluation recursion nests), and
+aggregate the (predicted cost, measured time) pairs into the
+calibration report that tells you where the APCT cost model drifts
+from reality.
 
     PYTHONPATH=src python examples/tracing.py
 """
 import sys
 sys.path.insert(0, "src")
+
+import os
+import tempfile
+
+import jax
 
 from repro import compiler, obs
 from repro.core.pattern import Pattern
@@ -30,11 +37,18 @@ p = Pattern(5, [(u, v) for u in range(5) for v in range(u + 1, 5)
 # span per IR evaluation beneath it.  Values are fenced
 # (jax.block_until_ready) before each span closes, so spans time the
 # work, not the async enqueue.
+# The same run under the JAX profiler writes a trace that TensorBoard's
+# profile plugin and Perfetto open: the program's spans (gpm.compile,
+# gpm.node, gpm.contract, gpm.join, gpm.upload, gpm.readback, ...) on
+# the host timeline, the device's operations under them.
+out_dir = tempfile.mkdtemp(prefix="k5me-")
 tracer = obs.Tracer()
+jax.profiler.start_trace(out_dir)
 cp = compiler.compile(p, graph, cache=False)
 cp.tracer = tracer
 count = cp.count(p)
-print(f"count = {count:,.0f} on {graph}")
+jax.profiler.stop_trace()
+print(f"count = {count:,.0f} on {graph}; profiler trace under {out_dir}")
 
 # --- 2. read the span tree ------------------------------------------------
 # Each span carries the node key, node class, cut size, the route the
@@ -48,14 +62,16 @@ for span in tracer.walk():
 # Coverage: how much of the end-to-end read the per-node spans explain.
 print(f"node coverage of wall time: {tracer.coverage():.1%}")
 
+# The counter increments made during the tracer's reads: bytes copied
+# between host and device by site, and re-traces by the span they
+# happened in.
+for name in ("transfer.h2d_bytes", "transfer.d2h_bytes", "jax.traces"):
+    print(f"  {name:24s} {tracer.total(name):,.0f}")
+
 # --- 3. export ------------------------------------------------------------
-# Span-tree JSON for tooling; *.chrome.json writes the Chrome
-# "traceEvents" format — open chrome://tracing (or Perfetto) and load it
-# to see the plan execute on a timeline.  `mine.py --trace=FILE` does
-# exactly this for full workloads.
-tracer.save("/tmp/k5me_trace.json")
-tracer.save("/tmp/k5me_trace.chrome.json")
-print("wrote /tmp/k5me_trace.json and /tmp/k5me_trace.chrome.json")
+# Span-tree JSON for tooling (obs.drift reads it); `mine.py --trace=FILE`
+# does exactly this for full workloads.
+print("wrote", tracer.save(os.path.join(out_dir, "k5me_trace.json")))
 
 # --- 4. the drift report --------------------------------------------------
 # Compilation stored each committed node's predicted APCT cost in

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 from typing import Iterable, Optional, Union
 
+from repro import obs
 from repro.core.pattern import Pattern
 from repro.graph.storage import Graph
 from repro.compiler import cache as _cache_mod
@@ -150,6 +151,7 @@ def _add_local_outputs(plan, patterns, graph, apct, budget, counter,
     plan.meta["local_cuts"] = local_cuts
 
 
+@obs.span("compile")
 def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
             apct=None, counter=None, cache: Optional[PlanCache] = None,
             budget: int = 1 << 27, max_cutjoin_cut: int = 3,
@@ -282,7 +284,6 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
 
     held = None
     if morph_store is not None:
-        from repro import obs as _obs
         gsig = _cache_mod.graph_signature(graph)
         derived = [_morph.derive(p, morph_store, gsig) for p in patterns]
         if all(d.complete for d in derived) and not domains and not local:
@@ -291,7 +292,7 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
             # plan — lowering answers every hom node from the store
             # (route "morph-derive"), so no contraction ever runs
             for _ in patterns:
-                _obs.counter("morph.hits")
+                obs.counter("morph.hits")
             plan = frontend.assemble(
                 [(p, frontend.direct_candidate(p)) for p in patterns])
             plan.meta.update({
@@ -315,7 +316,7 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
                          mesh=mesh, count_store=morph_store)
         for d in derived:
             if d.missing:
-                _obs.counter("morph.missing_compiles")
+                obs.counter("morph.missing_compiles")
         # partial closure (or a domains/local request): fall through to
         # the search, but hand costing the held hom pool — held
         # contractions price at ~0 and execute from the store
@@ -323,17 +324,20 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
 
     if apct is None:
         from repro.core.apct import APCT
-        apct = APCT(graph)
-    per_pattern = [(p, frontend.pattern_candidates(
-        p, graph_n=graph.n, budget=budget,
-        max_cutjoin_cut=max_cutjoin_cut)) for p in patterns]
+        with obs.span("apct"):
+            apct = APCT(graph)
+    with obs.span("candidates"):
+        per_pattern = [(p, frontend.pattern_candidates(
+            p, graph_n=graph.n, budget=budget,
+            max_cutjoin_cut=max_cutjoin_cut)) for p in patterns]
     label_fracs = _label_fracs(patterns, graph)
     node_costs: dict = {}
-    selections, total_cost = costing.select_candidates(
-        per_pattern, apct, graph.n, budget, counter=counter,
-        label_fracs=label_fracs, node_costs=node_costs,
-        devices=mesh_devices, held=held)
-    plan = frontend.assemble(selections)
+    with obs.span("costing"):
+        selections, total_cost = costing.select_candidates(
+            per_pattern, apct, graph.n, budget, counter=counter,
+            label_fracs=label_fracs, node_costs=node_costs,
+            devices=mesh_devices, held=held)
+        plan = frontend.assemble(selections)
     if domains:
         for p in patterns:
             for node in frontend.domain_candidate(p).nodes:
@@ -362,14 +366,16 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
                  for p, cand in selections},
     })
     if verify:
-        from repro import analysis, obs
-        ginfo = analysis.GraphInfo.from_graph(graph)
-        # graph statistics ride in meta so cached plans re-verify their
-        # budget pass without the graph; the precert copy is advisory
-        # (observability/examples) — lowering recomputes the certificate
-        # from the graph it actually binds, never trusting cached meta
-        plan.meta["graph_info"] = ginfo.to_dict()
-        result = analysis.verify(plan, graph_info=ginfo, budget=budget)
+        from repro import analysis
+        with obs.span("verify"):
+            ginfo = analysis.GraphInfo.from_graph(graph)
+            # graph statistics ride in meta so cached plans re-verify
+            # their budget pass without the graph; the precert copy is
+            # advisory (observability/examples) — lowering recomputes
+            # the certificate from the graph it actually binds, never
+            # trusting cached meta
+            plan.meta["graph_info"] = ginfo.to_dict()
+            result = analysis.verify(plan, graph_info=ginfo, budget=budget)
         result.raise_if_failed()
         plan.meta["precert"] = dict(result.precert)
         for diag in result.warnings:

@@ -269,9 +269,15 @@ class CompiledPlan:
             return self._values[key]
         node = self.plan.nodes[key]
         self.stats["node_evals"] += 1
+        cut = getattr(node, "cut_size", None)
         tr = self.tracer
-        if tr is None:                   # the default: no span machinery
-            val = self._eval(node)
+        if tr is None:
+            # the default: the profiler's ``gpm.node`` span alone, which
+            # a traced eval's node span opens as well
+            stats = {} if cut is None else {"cut": cut}
+            with obs.span("node", key=key, cls=type(node).__name__,
+                          **stats):
+                val = self._eval(node)
         else:
             # one span per node eval, nested by the recursion itself
             # (refs evaluated inside ``_eval`` open child spans; memo
@@ -281,7 +287,6 @@ class CompiledPlan:
             # span only after JAX async dispatch has really finished.
             attrs = {"predicted":
                      self.plan.meta.get("node_costs", {}).get(key)}
-            cut = getattr(node, "cut_size", None)
             if cut is not None:
                 attrs["cut_size"] = cut
             with tr.span(key, kind=type(node).__name__, **attrs):
@@ -367,15 +372,16 @@ class CompiledPlan:
         M = self._factors.get(key)
         if M is None:
             vals = [(coeff, self.value(ref)) for coeff, ref in terms]
-            if any(isinstance(v, jax.Array) for _, v in vals):
-                with self.counter._x64():
-                    M = jnp.zeros((self.graph.n,) * ndim, jnp.float64)
+            with obs.span("combine", terms=len(terms)):
+                if any(isinstance(v, jax.Array) for _, v in vals):
+                    with self.counter._x64():
+                        M = jnp.zeros((self.graph.n,) * ndim, jnp.float64)
+                        for coeff, v in vals:
+                            M = M + coeff * jnp.asarray(v, jnp.float64)
+                else:
+                    M = np.zeros((self.graph.n,) * ndim)
                     for coeff, v in vals:
-                        M = M + coeff * jnp.asarray(v, jnp.float64)
-            else:
-                M = np.zeros((self.graph.n,) * ndim)
-                for coeff, v in vals:
-                    M = M + coeff * np.asarray(v, np.float64)
+                        M = M + coeff * np.asarray(v, np.float64)
             self._factors[key] = M
         return M
 
@@ -438,7 +444,7 @@ class CompiledPlan:
             return block
         tr = self.tracer
         ctx = (tr.span(f"guard:{node.key}", kind="guard-scan")
-               if tr is not None else nullcontext())
+               if tr is not None else obs.span("guard_scan", key=node.key))
         with ctx:
             maxes = [self._factor_max(terms, len(ax), M)
                      for terms, M, ax in zip(node.factors, Ms, axes)]
@@ -510,40 +516,45 @@ class CompiledPlan:
                     from repro.distributed import cutjoin as dcj
                     self._annotate(route="kernel-sharded",
                                    mesh_axes=["data"], num_shards=shards)
-                    if node.cut_size <= 2:
-                        return dcj.sharded_cutjoin(
-                            Ms, mesh=self.mesh,
-                            distinct=node.cut_size >= 2, block=block)
-                    return dcj.sharded_cutjoin3(Ms, axes, n=self.graph.n,
-                                                mesh=self.mesh,
-                                                block=block)
+                    with obs.span("join", route="kernel-sharded"):
+                        if node.cut_size <= 2:
+                            return dcj.sharded_cutjoin(
+                                Ms, mesh=self.mesh,
+                                distinct=node.cut_size >= 2, block=block)
+                        return dcj.sharded_cutjoin3(
+                            Ms, axes, n=self.graph.n, mesh=self.mesh,
+                            block=block)
                 self._annotate(route="kernel")
-                if node.cut_size <= 2:
-                    return ops.cutjoin_reduce(Ms,
-                                              distinct=node.cut_size >= 2,
-                                              block=block)
-                return ops.cutjoin_reduce3(Ms, axes, n=self.graph.n,
-                                           block=block)
+                with obs.span("join", route="kernel"):
+                    if node.cut_size <= 2:
+                        return ops.cutjoin_reduce(
+                            Ms, distinct=node.cut_size >= 2, block=block)
+                    return ops.cutjoin_reduce3(Ms, axes, n=self.graph.n,
+                                               block=block)
             # factor magnitudes exceed what chunked f32 can represent
             # exactly: fall through to the f64 XLA join
             obs.counter("cutjoin.kernel_fallbacks", cut=node.cut_size)
-        Ms = self._dense_expand(Ms, axes, node.cut_size)
-        if node.cut_size >= 2:               # injectivity of the cut tuple
-            Ms.append(self._mask(node.cut_size))
+        with obs.span("expand", cut=node.cut_size):
+            Ms = self._dense_expand(Ms, axes, node.cut_size)
+            if node.cut_size >= 2:           # injectivity of the cut tuple
+                Ms.append(self._mask(node.cut_size))
         if shards > 1 and node.cut_size <= 3:
             # guard refusal / cutjoin_kernel=False under a mesh: the f64
             # dense join still shards (pure XLA, no chunking, no guard)
             from repro.distributed import cutjoin as dcj
             self._annotate(route="xla-sharded", mesh_axes=["data"],
                            num_shards=shards)
-            return dcj.sharded_dense_join(Ms, node.cut_size,
-                                          mesh=self.mesh)
+            with obs.span("join", route="xla-sharded"):
+                return dcj.sharded_dense_join(Ms, node.cut_size,
+                                              mesh=self.mesh)
         if shards > 1:
             self._shard_fallback("wide-cut")
         self._annotate(route="xla-dense")
-        with self.counter._x64():
-            return float(_join_reduce(jnp.stack([jnp.asarray(M)
-                                                 for M in Ms])))
+        with obs.span("join", route="xla-dense"), self.counter._x64():
+            stack = jnp.stack([obs.upload(M, site="xla_factors")
+                               for M in Ms])
+            return float(obs.readback(_join_reduce(stack),
+                                      site="xla_result"))
 
     def _eval_local(self, node: LocalCount) -> np.ndarray:
         """The decomposition join without the final reduce.  Reduce-free
@@ -560,10 +571,11 @@ class CompiledPlan:
         self._annotate(factor_shapes=[list(np.shape(M)) for M in Ms])
         if node.cut_size == 1 or len(node.keep) == node.cut_size:
             self._annotate(route="dense-product")
-            dense = self._dense_expand(Ms, axes, node.cut_size)
-            out = np.array(dense[0], np.float64)
-            for M in dense[1:]:
-                out *= M
+            with obs.span("join", route="dense-product"):
+                dense = self._dense_expand(Ms, axes, node.cut_size)
+                out = np.array(dense[0], np.float64)
+                for M in dense[1:]:
+                    out *= M
             if node.corrections:
                 out -= self._combine(node.corrections, len(node.keep))
             self._zero_collisions(out)       # injectivity of the cut tuple
@@ -579,24 +591,25 @@ class CompiledPlan:
                 from repro.distributed import cutjoin as dcj
                 self._annotate(route="kernel-sharded-keep",
                                mesh_axes=["data"], num_shards=shards)
-                if node.cut_size == 2:
-                    out = dcj.sharded_cutjoin_keep(Ms, keep=axis,
-                                                   mesh=self.mesh,
-                                                   block=block)
-                else:
-                    out = dcj.sharded_cutjoin3_keep(Ms, axes, keep=axis,
-                                                    n=self.graph.n,
-                                                    mesh=self.mesh,
-                                                    block=block)
+                with obs.span("join", route="kernel-sharded-keep"):
+                    if node.cut_size == 2:
+                        out = dcj.sharded_cutjoin_keep(Ms, keep=axis,
+                                                       mesh=self.mesh,
+                                                       block=block)
+                    else:
+                        out = dcj.sharded_cutjoin3_keep(
+                            Ms, axes, keep=axis, n=self.graph.n,
+                            mesh=self.mesh, block=block)
             elif block is not None:          # f32 chunks provably exact
                 self._annotate(route="kernel-keep")
-                if node.cut_size == 2:
-                    out = ops.cutjoin_reduce_keep(Ms, keep=axis,
-                                                  block=block)
-                else:
-                    out = ops.cutjoin_reduce3_keep(Ms, axes, keep=axis,
-                                                   n=self.graph.n,
-                                                   block=block)
+                with obs.span("join", route="kernel-keep"):
+                    if node.cut_size == 2:
+                        out = ops.cutjoin_reduce_keep(Ms, keep=axis,
+                                                      block=block)
+                    else:
+                        out = ops.cutjoin_reduce3_keep(Ms, axes, keep=axis,
+                                                       n=self.graph.n,
+                                                       block=block)
             else:
                 obs.counter("cutjoin.kernel_fallbacks", cut=node.cut_size,
                             keep=True)
@@ -605,23 +618,29 @@ class CompiledPlan:
             # dense keep join still shards (pure XLA, no chunking, no
             # guard) — mirroring the scalar route's ``xla-sharded``
             from repro.distributed import cutjoin as dcj
-            dense = self._dense_expand(Ms, axes, node.cut_size)
-            dense.append(self._mask(node.cut_size))
+            with obs.span("expand", cut=node.cut_size):
+                dense = self._dense_expand(Ms, axes, node.cut_size)
+                dense.append(self._mask(node.cut_size))
             self._annotate(route="xla-sharded-keep", mesh_axes=["data"],
                            num_shards=shards)
-            out = dcj.sharded_dense_join_keep(dense, node.cut_size,
-                                              keep=axis, mesh=self.mesh)
+            with obs.span("join", route="xla-sharded-keep"):
+                out = dcj.sharded_dense_join_keep(dense, node.cut_size,
+                                                  keep=axis, mesh=self.mesh)
         if out is None:
             self._annotate(route="xla-keep")
-            dense = self._dense_expand(Ms, axes, node.cut_size)
-            with self.counter._x64():
-                stack = jnp.stack([jnp.asarray(M) for M in dense])
+            with obs.span("expand", cut=node.cut_size):
+                dense = self._dense_expand(Ms, axes, node.cut_size)
+            with obs.span("join", route="xla-keep"), self.counter._x64():
+                stack = jnp.stack([obs.upload(M, site="xla_factors")
+                                   for M in dense])
                 if node.cut_size == 2:
-                    out = np.asarray(_join_keep(stack, axis), np.float64)
+                    res = _join_keep(stack, axis)
                 else:
-                    out = np.asarray(
-                        _join_keep3(stack, jnp.asarray(self._mask(3)),
-                                    axis), np.float64)
+                    res = _join_keep3(
+                        stack, obs.upload(self._mask(3), site="xla_factors"),
+                        axis)
+                out = np.asarray(obs.readback(res, site="xla_result"),
+                                 np.float64)
         if node.corrections:
             out = out - self._combine(node.corrections, 1)
         return out

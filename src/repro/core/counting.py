@@ -17,11 +17,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import homomorphism as H
 from repro.core.motifs import motif_patterns
 from repro.core.pattern import Pattern, free_skeleton, mark_free
 from repro.core.quotient import mobius, partitions, quotient_terms
 from repro.graph.storage import Graph
+
+
+def _sharded_upload(build, graph: Graph, mesh, dtype, *, site: str):
+    """``build(graph, mesh, dtype)``, a sharded array filled from host
+    blocks, in a ``gpm.upload`` span, its bytes counted as
+    ``obs.upload`` counts them."""
+    with obs.span("upload", site=site):
+        out = build(graph, mesh, dtype)
+    obs.counter("transfer.h2d_bytes", out.nbytes, site=site)
+    return out
 
 
 def _quotient_order(q: Pattern, cut_blocks: frozenset | None):
@@ -72,8 +83,9 @@ class CountingEngine:
         for or hold it."""
         if self._A_dense is None:
             with self._x64():
-                self._A_dense = jnp.asarray(
-                    self.graph.dense_adjacency(self._np_dtype, pad=False))
+                self._A_dense = obs.upload(
+                    self.graph.dense_adjacency(self._np_dtype, pad=False),
+                    site="adjacency")
         return self._A_dense
 
     @property
@@ -84,8 +96,9 @@ class CountingEngine:
             return None
         if self._labels_dense is None:
             with self._x64():
-                self._labels_dense = jnp.asarray(
-                    self.graph.label_indicators(self._np_dtype, pad=False))
+                self._labels_dense = obs.upload(
+                    self.graph.label_indicators(self._np_dtype, pad=False),
+                    site="labels")
         return self._labels_dense
 
     # -- sharded-contraction route --------------------------------------------
@@ -101,8 +114,9 @@ class CountingEngine:
         if self._A_blocks is None:
             from repro.distributed import contract as C
             with self._x64():
-                self._A_blocks = C.adjacency_blocks(self.graph, self.mesh,
-                                                    self._np_dtype)
+                self._A_blocks = _sharded_upload(
+                    C.adjacency_blocks, self.graph, self.mesh,
+                    self._np_dtype, site="adjacency")
         return self._A_blocks
 
     def _unary_blocks(self, p: Pattern):
@@ -113,8 +127,9 @@ class CountingEngine:
         from repro.distributed import contract as C
         with self._x64():
             if self._label_rows is None:
-                self._label_rows = C.label_blocks(self.graph, self.mesh,
-                                                  self._np_dtype)
+                self._label_rows = _sharded_upload(
+                    C.label_blocks, self.graph, self.mesh, self._np_dtype,
+                    site="labels")
             L = self._label_rows.shape[0]
             zero = jnp.zeros_like(self._label_rows[0])
             return {v: (self._label_rows[l] if 0 <= l < L else zero)
@@ -160,20 +175,22 @@ class CountingEngine:
             # ordered enumeration.  hom(K_k) = k! * #cliques.
             import math
             from repro.core.cliques import clique_count
-            val = float(math.factorial(c.n) * clique_count(self.graph, c.n))
+            with obs.span("enumerate", k=c.n):
+                val = float(math.factorial(c.n)
+                            * clique_count(self.graph, c.n))
         elif self.mesh is not None:
             from repro.distributed import contract as C
             with self._x64():
-                val = float(C.sharded_hom(c, self._blocks(),
-                                          mesh=self.mesh, n=self.graph.n,
-                                          order=order,
-                                          unary=self._unary_blocks(c),
-                                          budget=self.budget))
+                val = float(obs.readback(
+                    C.sharded_hom(c, self._blocks(), mesh=self.mesh,
+                                  n=self.graph.n, order=order,
+                                  unary=self._unary_blocks(c),
+                                  budget=self.budget), site="contract"))
         else:
             with self._x64():
-                val = float(H.hom_count(c, self.A, order=order,
-                                        unary=self._unary_for(c),
-                                        budget=self.budget))
+                val = float(obs.readback(
+                    self._contract(c, order=order, unary=self._unary_for(c)),
+                    site="contract"))
         self.hom_memo[c] = val
         return val
 
@@ -207,12 +224,22 @@ class CountingEngine:
                                     budget=self.budget)
         else:
             with self._x64():
-                val = np.asarray(H.hom_count(
-                    p, self.A, order=tuple(order) if order else None,
-                    free=tuple(free), unary=self._unary_for(p),
-                    budget=self.budget))
+                val = obs.readback(self._contract(
+                    p, order=tuple(order) if order else None,
+                    free=tuple(free), unary=self._unary_for(p)),
+                    site="contract")
         self.hom_free_memo[key] = val
         return val
+
+    def _contract(self, p: Pattern, **kw):
+        """``H.hom_count`` over the dense adjacency in a ``gpm.contract``
+        span that closes once the device has the result: the device wait
+        sits inside it, the copy back to the host (``obs.readback``)
+        after it."""
+        A = self.A                  # built and uploaded in its own spans
+        with obs.span("contract", free=len(kw.get("free", ()))):
+            return jax.block_until_ready(
+                H.hom_count(p, A, budget=self.budget, **kw))
 
     # -- injective tuples / embeddings ----------------------------------------
     def inj(self, p: Pattern, cut=None) -> float:

@@ -144,10 +144,10 @@ def sharded_cutjoin(factors, *, mesh: Mesh, distinct: bool = True,
     interpret = _auto_interpret(interpret)
     stack = _mr._pair_stack(factors)         # vectors: (k, n/128, 128)
     with _x64():
-        return float(_sharded_pair(
+        return float(obs.readback(_sharded_pair(
             stack, mesh=mesh, distinct=distinct and np.ndim(factors[0]) == 2,
             keep=False, q=0, block=block, tile=_tile(interpret, tile),
-            interpret=interpret))
+            interpret=interpret), site="kernel_result"))
 
 
 def sharded_cutjoin_keep(factors, *, keep: int = 0, mesh: Mesh,
@@ -172,6 +172,7 @@ def sharded_cutjoin_keep(factors, *, keep: int = 0, mesh: Mesh,
         out = _sharded_pair(stack, mesh=mesh, distinct=distinct, keep=True,
                             q=1 if keep == 0 else 0, block=block,
                             tile=_tile(interpret, tile), interpret=interpret)
+        out = obs.readback(out, site="kernel_result")
         return np.asarray(out, np.float64)[:n]
 
 
@@ -231,10 +232,11 @@ def sharded_cutjoin3(factors, axes, *, n: int, mesh: Mesh,
     same ``exact_block`` contract as ``sharded_cutjoin`` applies."""
     interpret = _auto_interpret(interpret)
     with _x64():
-        return float(_sharded_tri(factors, axes, n=n, mesh=mesh,
-                                  distinct=distinct, keep=False, q=0,
-                                  block=block, tile=_tile(interpret, tile),
-                                  interpret=interpret))
+        return float(obs.readback(
+            _sharded_tri(factors, axes, n=n, mesh=mesh, distinct=distinct,
+                         keep=False, q=0, block=block,
+                         tile=_tile(interpret, tile), interpret=interpret),
+            site="kernel_result"))
 
 
 def sharded_cutjoin3_keep(factors, axes, *, keep: int, n: int,
@@ -255,6 +257,7 @@ def sharded_cutjoin3_keep(factors, axes, *, keep: int, n: int,
                            distinct=distinct, keep=True, q=perm.index(0),
                            block=block, tile=_tile(interpret, tile),
                            interpret=interpret)
+        out = obs.readback(out, site="kernel_result")
         return np.asarray(out, np.float64)[:n]
 
 
@@ -285,9 +288,11 @@ def sharded_dense_join(Ms, k: int, *, mesh: Mesh) -> float:
     bit-for-bit with the single-device ``_join_reduce``."""
     d = num_shards(mesh)
     with _x64():
-        stack = jnp.stack([jnp.asarray(M, jnp.float64) for M in Ms])
+        stack = jnp.stack([obs.upload(M, jnp.float64, site="xla_factors")
+                           for M in Ms])
         stack = _pad_axis(stack, 1, _ceil_to(stack.shape[1], d))
-        return float(_dense_scalar_fn(mesh, k)(stack))
+        return float(obs.readback(_dense_scalar_fn(mesh, k)(stack),
+                                  site="xla_result"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -328,11 +333,13 @@ def sharded_dense_join_keep(Ms, k: int, *, keep: int,
     assert 0 <= keep < k
     d = num_shards(mesh)
     with _x64():
-        stack = jnp.stack([jnp.asarray(M, jnp.float64) for M in Ms])
+        stack = jnp.stack([obs.upload(M, jnp.float64, site="xla_factors")
+                           for M in Ms])
         assert stack.ndim == k + 1
         n = stack.shape[1 + keep]
         stack = _pad_axis(stack, 1, _ceil_to(stack.shape[1], d))
         out = _dense_keep_fn(mesh, k, keep)(stack)
+        out = obs.readback(out, site="xla_result")
         return np.asarray(out, np.float64)[:n]
 
 

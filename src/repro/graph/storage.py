@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
+
 TILE = 128
 
 
@@ -91,9 +93,10 @@ class Graph:
         key = (np.dtype(dtype), pad)
         if self._dense is None or self._dense[0] != key:
             n = self.n_padded if pad else self.n
-            a = np.zeros((n, n), dtype)
-            a[self.edges[:, 0], self.edges[:, 1]] = 1
-            a[self.edges[:, 1], self.edges[:, 0]] = 1
+            with obs.span("adjacency", n=n):
+                a = np.zeros((n, n), dtype)
+                a[self.edges[:, 0], self.edges[:, 1]] = 1
+                a[self.edges[:, 1], self.edges[:, 0]] = 1
             self._dense = (key, a)
         return self._dense[1]
 

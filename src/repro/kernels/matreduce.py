@@ -57,6 +57,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
+
 
 def _pad_to(x, multiples):
     """Zero-pad every axis of ``x`` up to the matching tile multiple."""
@@ -227,7 +229,8 @@ def _offsets_or_zero(offsets, naxes: int):
 def _pair_stack(factors):
     """f32 (k, M, N) stack; (n,) vectors (|cut| = 1) are laid out as
     (k, n/128, 128) lane rows so one kernel serves both cut sizes."""
-    stack = jnp.stack([jnp.asarray(F, jnp.float32) for F in factors])
+    stack = jnp.stack([obs.upload(F, jnp.float32, site="kernel_factors")
+                       for F in factors])
     if stack.ndim == 2:
         stack = _pad_to(stack, (1, LANE)).reshape(stack.shape[0], -1, LANE)
     assert stack.ndim == 3        # rectangular slices legal (sharded rows)
@@ -255,9 +258,10 @@ def prod_reduce(factors, *, distinct: bool = True, block: int = 128,
     tr, tc, c = _pair_tiles(stack.shape[1], stack.shape[2], block, tile)
     stack = _pad_to(stack, (1, tr, tc))
     with jax.enable_x64(True):
-        return float(_pairjoin(stack, _offsets_or_zero(offsets, 2),
-                               distinct=distinct, keep=False, chunk=c,
-                               tr=tr, tc=tc, interpret=interpret))
+        return float(obs.readback(
+            _pairjoin(stack, _offsets_or_zero(offsets, 2),
+                      distinct=distinct, keep=False, chunk=c, tr=tr, tc=tc,
+                      interpret=interpret), site="kernel_result"))
 
 
 def prod_reduce_keep(factors, *, keep: int = 0, distinct: bool = True,
@@ -287,6 +291,7 @@ def prod_reduce_keep(factors, *, keep: int = 0, distinct: bool = True,
     with jax.enable_x64(True):
         out = _pairjoin(stack, off, distinct=distinct, keep=True, chunk=c,
                         tr=tr, tc=tc, interpret=interpret)
+        out = obs.readback(out, site="kernel_result")
     return np.asarray(out, np.float64)[:n]
 
 
@@ -393,7 +398,7 @@ def _tri_normalise(factors, axes, n: int, tiles):
     for F, ax in zip(factors, axes):
         ax = tuple(ax)
         assert ax == tuple(sorted(set(ax))) and set(ax) <= {0, 1, 2}
-        F = jnp.asarray(F, jnp.float32)
+        F = obs.upload(F, jnp.float32, site="kernel_factors")
         assert F.ndim == len(ax) and all(s == n for s in F.shape), \
             (F.shape, ax, n)
         covered |= set(ax)
@@ -429,10 +434,11 @@ def tri_reduce(factors, axes, *, n: int, distinct: bool = True,
     bx, by, bz, c = _tri_tiles(n, block, tile)
     stacked, present = _tri_normalise(factors, axes, n, (bx, by, bz))
     with jax.enable_x64(True):
-        return float(_trijoin(*stacked, offsets=_offsets_or_zero(offsets, 3),
-                              present=present, distinct=distinct,
-                              keep=False, chunk=c, bx=bx, by=by, bz=bz,
-                              interpret=interpret))
+        return float(obs.readback(
+            _trijoin(*stacked, offsets=_offsets_or_zero(offsets, 3),
+                     present=present, distinct=distinct, keep=False,
+                     chunk=c, bx=bx, by=by, bz=bz, interpret=interpret),
+            site="kernel_result"))
 
 
 def tri_permute(factors, axes, keep: int):
@@ -471,6 +477,7 @@ def tri_reduce_keep(factors, axes, *, keep: int, n: int,
         out = _trijoin(*stacked, offsets=off, present=present,
                        distinct=distinct, keep=True, chunk=c, bx=bx, by=by,
                        bz=bz, interpret=interpret)
+        out = obs.readback(out, site="kernel_result")
     return np.asarray(out, np.float64)[:n]
 
 

@@ -79,9 +79,9 @@ def main(argv=None):
                          "precertification summary)")
     ap.add_argument("--trace", default=None, metavar="FILE",
                     help="record per-node execution spans on compiled "
-                    "plans and write the trace to FILE (JSON; a "
-                    "*.chrome.json suffix writes chrome://tracing "
-                    "format instead)")
+                    "plans and write the span tree to FILE (JSON); for "
+                    "a timeline, run under jax.profiler, whose trace "
+                    "holds the same spans (gpm.*) beside the device ops")
     ap.add_argument("--metrics", action="store_true",
                     help="print the process metrics registry "
                     "(counters/gauges/histograms) after the run")

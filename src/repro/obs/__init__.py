@@ -1,13 +1,19 @@
-"""Observability: plan-execution tracing, unified metrics, drift
-accounting.
+"""Observability: program spans on the profiler's clock, unified
+metrics, drift accounting.
 
 Three pieces, all zero-dependency (stdlib; jax only behind a lazy
 fence):
 
-* ``trace`` — ``Tracer``/``Span``: per-node span trees over plan
-  execution, exportable as JSON or Chrome ``chrome://tracing`` format.
-  Attach with ``compiled_plan.tracer = Tracer()``; disabled (the
-  default) costs one ``is None`` check per node eval.
+* ``trace`` — one span system.  ``span("contract")`` opens
+  ``gpm.contract`` in the JAX profiler's trace, on the device
+  operations' clock, at every layer boundary of the program (compile,
+  node evaluation, Contract, joins, host<->device copies); ``upload`` /
+  ``readback`` move tensors under their spans and count the bytes.
+  ``Tracer``/``Span`` record per-node span trees over plan execution,
+  exportable as JSON; attach with ``compiled_plan.tracer = Tracer()``
+  (disabled, the default, costs one ``is None`` check per node eval).
+  A tracer also keeps the counter increments of its own reads
+  (``Tracer.counts``).
 * ``metrics`` — the process-wide ``MetricsRegistry`` (labelled
   counters/gauges/histograms) behind module-level helpers, plus
   ``StatsView``, the dict-shaped facade that keeps every pre-existing
@@ -24,8 +30,11 @@ Typical use::
     tr = obs.Tracer()
     cp = compiler.compile(p, g)
     cp.tracer = tr
+    jax.profiler.start_trace("prof")         # optional: the timeline
     cp.count(p)
-    tr.save("out.json")                      # or out.chrome.json
+    jax.profiler.stop_trace()                # gpm.* spans + device ops
+    tr.save("out.json")
+    tr.total("transfer.d2h_bytes")           # counts of this tracer's reads
     report = obs.drift.aggregate(obs.drift.pairs_from_trace(tr.to_dict()))
 
     obs.counter("my.events", kind="x")       # unified metrics
@@ -35,16 +44,12 @@ from __future__ import annotations
 
 from repro.obs import drift
 from repro.obs.metrics import REGISTRY, MetricsRegistry, StatsView
-from repro.obs.trace import Span, Tracer, fence
+from repro.obs.trace import (Span, Tracer, counter, fence, readback, span,
+                             upload)
 
-__all__ = ["Tracer", "Span", "fence", "MetricsRegistry", "StatsView",
-           "REGISTRY", "drift", "counter", "gauge", "observe", "get",
-           "snapshot", "dump", "reset"]
-
-
-def counter(name: str, value: float = 1, **labels) -> float:
-    """Increment a labelled counter on the process registry."""
-    return REGISTRY.counter(name, value, **labels)
+__all__ = ["Tracer", "Span", "fence", "span", "upload", "readback",
+           "MetricsRegistry", "StatsView", "REGISTRY", "drift", "counter",
+           "gauge", "observe", "get", "snapshot", "dump", "reset"]
 
 
 def gauge(name: str, value: float, **labels):

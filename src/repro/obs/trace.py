@@ -1,4 +1,22 @@
-"""Plan-execution tracer: span trees over IR node evaluation.
+"""Program spans on the profiler's clock, and the plan-execution tracer.
+
+One span system.  ``span(name, **stats)`` opens a
+``jax.profiler.TraceAnnotation`` named ``"gpm." + name``: with the
+profiler running (``jax.profiler.start_trace`` / TensorBoard /
+Perfetto) it lands in the same trace as the device's operations, on
+the same clock, so any idle stretch of the device can be placed inside
+the program step that held it.  With no profiler running a span costs
+about a microsecond; ``stats`` become XPlane stats of the event and
+leave its name clean.  Spans sit at the program's layer boundaries
+(compile, node evaluation, Contract, joins, host<->device copies), never
+in hot inner loops, which keep counters instead.
+
+A stack of the open spans, per thread, labels what happens under them:
+the JAX duration listener installed with the first span counts
+``jax.traces`` (one per jaxpr trace) under the innermost open span,
+which answers "which step re-traced".  ``upload`` and ``readback`` are
+the host<->device copies of the mining path, each in its span and
+counted in ``transfer.h2d_bytes`` / ``transfer.d2h_bytes`` by site.
 
 ``Tracer`` records one ``Span`` per evaluated plan node (plus one root
 "execute" span per public read), nested exactly as the evaluation
@@ -7,28 +25,170 @@ factor tensors it had to materialise, a MobiusCombine span contains its
 term evaluations, and a node served from the plan's value memo opens no
 span at all.  Each span carries the node key, node class, cut size,
 the kernel-vs-XLA route actually taken, the ``exact_block`` guard
-outcome, factor shapes, and wall time from ``time.perf_counter``.
+outcome, factor shapes, and wall time from ``time.perf_counter``.  Its
+node and guard-scan spans open the profiler spans ``gpm.node`` and
+``gpm.guard_scan`` too, so the tree and the profiler trace name the
+same steps; the program's other spans go to the profiler only.  While
+one of its reads is open the tracer also keeps the counts of every
+``obs.counter`` increment (``Tracer.counts``), so one job's transfer
+bytes and re-traces are read off its tracer.
 
 JAX dispatch is asynchronous, so a span that closed the instant the
 kernel call returned would time the *enqueue*, not the work: callers
 fence the evaluated value with ``fence`` (``jax.block_until_ready``)
-before the span closes.  Lowering already converts node values to host
-floats/arrays (which forces a sync), so the fence is a cheap no-op on
-the common path and a correctness backstop everywhere else.
+before the span closes.
 
-Exports: ``to_dict``/``to_json`` (the span tree, with per-span self
-time and a root-coverage summary) and ``to_chrome`` (the Chrome
-``chrome://tracing`` / Perfetto "traceEvents" format — load the file at
-chrome://tracing to see the plan execute on a timeline).
+Exports: ``to_dict``/``to_json``/``save`` (the span tree, with per-span
+self time, a root-coverage summary and the counts), which
+``obs.drift`` and ``launch.mine --trace`` read.
 
-Zero-dependency: stdlib only, jax imported lazily inside ``fence``.
+Zero-dependency: stdlib only; jax and numpy are imported lazily, and
+without jax a span is a null context.
 """
 from __future__ import annotations
 
+import functools
 import json
+import threading
 import time
 from contextlib import contextmanager
-from typing import List, Optional
+from typing import Dict, List, Optional
+
+from repro.obs.metrics import REGISTRY
+
+PREFIX = "gpm."
+# the JAX monitoring event that marks one jaxpr trace
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+# Tracer span kinds -> the profiler span they open (the per-read
+# "execute" roots open none: the harness owns that name)
+PROFILE_KINDS = {"guard-scan": "guard_scan", "execute": None}
+
+_local = threading.local()
+_annotation = None                      # TraceAnnotation, resolved lazily
+
+
+def _state():
+    """This thread's open program spans and the Tracers with an open
+    read, innermost last."""
+    st = getattr(_local, "st", None)
+    if st is None:
+        st = _local.st = ([], [])
+    return st
+
+
+class _NullAnnotation:
+    def __init__(self, name, **stats):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        pass
+
+
+def _resolve():
+    """``jax.profiler.TraceAnnotation``, and the duration listener
+    registered once; the null annotation where jax is missing."""
+    global _annotation
+    try:
+        import jax
+    except ImportError:
+        _annotation = _NullAnnotation
+        return _annotation
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _on_duration(event: str, seconds: float, **_):
+    if event != TRACE_EVENT:
+        return
+    spans = _state()[0]
+    counter("jax.traces", span=spans[-1] if spans else "")
+
+
+class span:
+    """``with obs.span("contract", cut=2): ...`` — one program span,
+    ``gpm.<name>`` in the profiler trace.  ``set(**stats)`` adds stats
+    known only later (a route); as a decorator, ``@obs.span("compile")``
+    opens a fresh span around every call."""
+    __slots__ = ("name", "stats", "_ann")
+
+    def __init__(self, name: str, **stats):
+        self.name = name
+        self.stats = stats
+        self._ann = None
+
+    def __enter__(self):
+        ann = _annotation or _resolve()
+        self._ann = ann(PREFIX + self.name, **self.stats)
+        _state()[0].append(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            self._ann.__exit__(*exc)
+        finally:
+            _state()[0].pop()
+        return False
+
+    def set(self, **stats):
+        self._ann.set_metadata(**stats)
+
+    def __call__(self, fn):
+        name, stats = self.name, self.stats
+
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with span(name, **stats):
+                return fn(*args, **kwargs)
+        return inner
+
+
+def counter(name: str, value: float = 1, **labels) -> float:
+    """Increment a registry counter, and the counts of the Tracer whose
+    read is open on this thread."""
+    total = REGISTRY.counter(name, value, **labels)
+    tracers = _state()[1]
+    if tracers:
+        tracers[-1]._count(name, value, labels)
+    return total
+
+
+def upload(x, dtype=None, *, site: str):
+    """``jnp.asarray(x, dtype)`` of a host array in a ``gpm.upload``
+    span, its bytes counted as they cross (the converted copy's: an f64
+    host array uploaded as f32 moves 4 bytes an element).  A jax Array
+    (already on the device, or a tracer) passes through uncounted."""
+    import jax
+    import jax.numpy as jnp
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, dtype)
+    with span("upload", site=site):
+        out = jnp.asarray(x, dtype)
+    counter("transfer.h2d_bytes", out.nbytes, site=site)
+    return out
+
+
+def readback(x, *, site: str):
+    """``np.asarray(x)`` of a device array in a ``gpm.readback`` span,
+    its bytes counted.  The wait for the device's result comes first,
+    outside the span, so the span holds the copy alone.  Host values
+    pass through uncounted."""
+    import jax
+    import numpy as np
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    jax.block_until_ready(x)
+    with span("readback", site=site):
+        out = np.asarray(x)
+    counter("transfer.d2h_bytes", out.nbytes, site=site)
+    return out
 
 
 def fence(value):
@@ -79,11 +239,14 @@ class Tracer:
     """Collects span trees across one or more plan executions.  Attach
     with ``compiled_plan.tracer = tracer``; every subsequent public read
     (``count`` / ``local_counts`` / ``exists`` / ``domains``) opens a
-    root span and nests node spans beneath it."""
+    root span and nests node spans beneath it.  ``counts`` holds the
+    ``obs.counter`` increments made while a read was open:
+    {name: {"k=v,...": total}}, as ``obs.snapshot`` keys them."""
 
     def __init__(self, meta: Optional[dict] = None):
         self.roots: List[Span] = []
         self._stack: List[Span] = []
+        self.counts: Dict[str, Dict[str, float]] = {}
         self.epoch = time.perf_counter()
         self.meta = dict(meta or {})
         if "backend" not in self.meta:
@@ -101,7 +264,15 @@ class Tracer:
             self._stack[-1].children.append(s)
         else:
             self.roots.append(s)
+            _state()[1].append(self)
         self._stack.append(s)
+        prof = PROFILE_KINDS.get(kind, "node")
+        ps = None
+        if prof is not None:
+            stats = {"key": name, "cls": kind}
+            if "cut_size" in attrs:
+                stats["cut"] = attrs["cut_size"]
+            ps = span(prof, **stats).__enter__()
         try:
             yield s
         except BaseException as e:
@@ -109,7 +280,14 @@ class Tracer:
             raise
         finally:
             s.t1 = time.perf_counter() - self.epoch
+            if ps is not None:
+                route = s.attrs.get("route")
+                if route is not None:
+                    ps.set(route=route)
+                ps.__exit__(None, None, None)
             self._stack.pop()
+            if not self._stack:
+                _state()[1].pop()
 
     def annotate(self, **attrs):
         """Attach attributes to the innermost open span (no-op outside
@@ -119,6 +297,16 @@ class Tracer:
 
     def current(self) -> Optional[Span]:
         return self._stack[-1] if self._stack else None
+
+    def _count(self, name: str, value: float, labels: dict):
+        key = ",".join(f"{k}={v}" for k, v in sorted(labels.items()))
+        series = self.counts.setdefault(name, {})
+        series[key] = series.get(key, 0) + value
+
+    def total(self, name: str) -> float:
+        """One counter's increments during this tracer's reads, summed
+        over its labels."""
+        return sum(self.counts.get(name, {}).values())
 
     # -- analysis ----------------------------------------------------------------
     def walk(self):
@@ -146,35 +334,16 @@ class Tracer:
         cov = self.coverage()
         return {"meta": dict(self.meta),
                 "coverage": cov,
+                "counts": {k: dict(v) for k, v in self.counts.items()},
                 "spans": [r.to_dict() for r in self.roots]}
 
     def to_json(self, indent: Optional[int] = 1) -> str:
         return json.dumps(self.to_dict(), indent=indent, sort_keys=True)
 
-    def to_chrome(self) -> dict:
-        """Chrome ``chrome://tracing`` "traceEvents" JSON: one complete
-        ("ph": "X") event per span, all on one pid/tid so nesting renders
-        as flame-graph depth."""
-        events = []
-        for s in self.walk():
-            events.append({"name": s.name, "cat": s.kind, "ph": "X",
-                           "ts": s.t0 * 1e6, "dur": s.duration_s * 1e6,
-                           "pid": 0, "tid": 0,
-                           "args": {k: repr(v) if not isinstance(
-                               v, (int, float, str, bool, type(None)))
-                               else v for k, v in s.attrs.items()}})
-        return {"traceEvents": events, "displayTimeUnit": "ms",
-                "otherData": dict(self.meta)}
-
-    def save(self, path: str, fmt: Optional[str] = None) -> str:
-        """Write the trace to ``path``.  ``fmt`` is "json" (the span
-        tree) or "chrome"; default infers chrome for paths ending in
-        ``.chrome.json``, span-tree JSON otherwise."""
-        if fmt is None:
-            fmt = "chrome" if path.endswith(".chrome.json") else "json"
+    def save(self, path: str) -> str:
+        """Write the span-tree JSON to ``path``.  The timeline view is
+        the profiler's trace, which holds the same spans beside the
+        device's operations."""
         with open(path, "w") as fh:
-            if fmt == "chrome":
-                json.dump(self.to_chrome(), fh, indent=1)
-            else:
-                fh.write(self.to_json())
+            fh.write(self.to_json())
         return path
