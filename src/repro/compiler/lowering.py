@@ -374,10 +374,13 @@ class CompiledPlan:
             vals = [(coeff, self.value(ref)) for coeff, ref in terms]
             with obs.span("combine", terms=len(terms)):
                 if any(isinstance(v, jax.Array) for _, v in vals):
+                    # from the first term, keeping its sharding: a zeros
+                    # start would first be whole on one device
                     with self.counter._x64():
-                        M = jnp.zeros((self.graph.n,) * ndim, jnp.float64)
+                        M = None
                         for coeff, v in vals:
-                            M = M + coeff * jnp.asarray(v, jnp.float64)
+                            term = coeff * jnp.asarray(v, jnp.float64)
+                            M = term if M is None else M + term
                 else:
                     M = np.zeros((self.graph.n,) * ndim)
                     for coeff, v in vals:
@@ -534,19 +537,22 @@ class CompiledPlan:
             # factor magnitudes exceed what chunked f32 can represent
             # exactly: fall through to the f64 XLA join
             obs.counter("cutjoin.kernel_fallbacks", cut=node.cut_size)
-        with obs.span("expand", cut=node.cut_size):
-            Ms = self._dense_expand(Ms, axes, node.cut_size)
-            if node.cut_size >= 2:           # injectivity of the cut tuple
-                Ms.append(self._mask(node.cut_size))
         if shards > 1 and node.cut_size <= 3:
             # guard refusal / cutjoin_kernel=False under a mesh: the f64
-            # dense join still shards (pure XLA, no chunking, no guard)
+            # dense join still shards (pure XLA, no chunking, no guard),
+            # its injectivity mask built inside each shard
             from repro.distributed import cutjoin as dcj
+            with obs.span("expand", cut=node.cut_size):
+                Ms = self._dense_expand(Ms, axes, node.cut_size)
             self._annotate(route="xla-sharded", mesh_axes=["data"],
                            num_shards=shards)
             with obs.span("join", route="xla-sharded"):
                 return dcj.sharded_dense_join(Ms, node.cut_size,
                                               mesh=self.mesh)
+        with obs.span("expand", cut=node.cut_size):
+            Ms = self._dense_expand(Ms, axes, node.cut_size)
+            if node.cut_size >= 2:           # injectivity of the cut tuple
+                Ms.append(self._mask(node.cut_size))
         if shards > 1:
             self._shard_fallback("wide-cut")
         self._annotate(route="xla-dense")
@@ -620,7 +626,6 @@ class CompiledPlan:
             from repro.distributed import cutjoin as dcj
             with obs.span("expand", cut=node.cut_size):
                 dense = self._dense_expand(Ms, axes, node.cut_size)
-                dense.append(self._mask(node.cut_size))
             self._annotate(route="xla-sharded-keep", mesh_axes=["data"],
                            num_shards=shards)
             with obs.span("join", route="xla-sharded-keep"):

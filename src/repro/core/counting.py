@@ -179,13 +179,10 @@ class CountingEngine:
                 val = float(math.factorial(c.n)
                             * clique_count(self.graph, c.n))
         elif self.mesh is not None:
-            from repro.distributed import contract as C
             with self._x64():
                 val = float(obs.readback(
-                    C.sharded_hom(c, self._blocks(), mesh=self.mesh,
-                                  n=self.graph.n, order=order,
-                                  unary=self._unary_blocks(c),
-                                  budget=self.budget), site="contract"))
+                    self._sharded_contract(c, order=order),
+                    site="contract"))
         else:
             with self._x64():
                 val = float(obs.readback(
@@ -214,14 +211,10 @@ class CountingEngine:
             return self.hom_free_memo[key]
         self.stats["hom_evals"] += 1
         if self.mesh is not None:
-            from repro.distributed import contract as C
             with self._x64():
-                val = C.sharded_hom(p, self._blocks(), mesh=self.mesh,
-                                    n=self.graph.n,
-                                    order=tuple(order) if order else None,
-                                    free=tuple(free),
-                                    unary=self._unary_blocks(p),
-                                    budget=self.budget)
+                val = self._sharded_contract(
+                    p, order=tuple(order) if order else None,
+                    free=tuple(free))
         else:
             with self._x64():
                 val = obs.readback(self._contract(
@@ -240,6 +233,26 @@ class CountingEngine:
         with obs.span("contract", free=len(kw.get("free", ()))):
             return jax.block_until_ready(
                 H.hom_count(p, A, budget=self.budget, **kw))
+
+    def _sharded_contract(self, p: Pattern, **kw):
+        """``distributed.contract.sharded_hom`` over the row-sharded
+        adjacency in a ``gpm.contract`` span that closes once the devices
+        have the result, as ``_contract`` does; the span names the shard
+        count and the step forms, and the plan's Tracer node gets each
+        step's description (``obs.note``).  The narrow steps' bound takes
+        the largest degree from the bound graph on every call."""
+        from repro.distributed import contract as C
+        blocks, unary = self._blocks(), self._unary_blocks(p)
+        degree = int(self.graph.degrees.max()) if self.graph.n else 0
+        steps: list = []
+        with obs.span("contract", sharded=self.contract_shards(),
+                      free=len(kw.get("free", ()))) as sp:
+            val = jax.block_until_ready(C.sharded_hom(
+                p, blocks, mesh=self.mesh, n=self.graph.n, unary=unary,
+                budget=self.budget, max_degree=degree, steps=steps, **kw))
+            sp.set(form=",".join(sorted({s["form"] for s in steps})))
+        obs.note("steps", steps)
+        return val
 
     # -- injective tuples / embeddings ----------------------------------------
     def inj(self, p: Pattern, cut=None) -> float:

@@ -51,7 +51,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.distributed import meshes
@@ -261,85 +261,100 @@ def sharded_cutjoin3_keep(factors, axes, *, keep: int, n: int,
         return np.asarray(out, np.float64)[:n]
 
 
-@functools.lru_cache(maxsize=None)
-def _dense_scalar_fn(mesh: Mesh, k: int):
-    """shard_map'd dense f64 join (the ``xla-sharded`` route): the
-    caller's pre-masked (nf, n, ..., n) stack row-sliced on the first
-    cut axis, local Π-then-Σ, psum."""
-    def local(stack):
-        return jax.lax.psum(jnp.sum(jnp.prod(stack, axis=0)), "data")
+def _distinct(block):
+    """Π_{a<b} [x_a != x_b] over one shard's (rows, n, ..., n) block of
+    the cut grid, from iotas: axis 0 starts at this shard's offset."""
+    start = jax.lax.axis_index("data") * block.shape[0]
+    idx = [jax.lax.broadcasted_iota(jnp.int32, block.shape, a)
+           + (start if a == 0 else 0) for a in range(block.ndim)]
+    keep = None
+    for a in range(block.ndim):
+        for b in range(a + 1, block.ndim):
+            ne = idx[a] != idx[b]
+            keep = ne if keep is None else keep & ne
+    return keep
 
-    in_specs = (P(*([None, "data"] + [None] * (k - 1))),)
-    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(), check_vma=False))
+
+@functools.lru_cache(maxsize=None)
+def _dense_fn(mesh: Mesh, nf: int, k: int, keep: Optional[int]):
+    """shard_map'd dense f64 join (the ``xla-sharded`` and
+    ``xla-sharded-keep`` routes): ``nf`` (n, ..., n) factors row-sliced
+    on cut axis 0, their product masked to distinct cut tuples from
+    iotas inside the shard, then Σ.  A scalar (``keep`` None) and a
+    kept axis other than 0 ``psum`` per-shard partials; keep == 0 is
+    the sharded axis, so each shard owns its output slice."""
+    def local(*factors):
+        prod = factors[0]
+        for F in factors[1:]:
+            prod = prod * F
+        if k >= 2:
+            prod = jnp.where(_distinct(prod), prod, 0.0)
+        if keep is None:
+            return jax.lax.psum(jnp.sum(prod), "data")
+        vec = jnp.sum(prod, axis=tuple(a for a in range(k) if a != keep))
+        return vec if keep == 0 else jax.lax.psum(vec, "data")
+
+    spec = P(*(["data"] + [None] * (k - 1)))
+    out_specs = P() if keep is None else P("data") if keep == 0 else P(None)
+    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec,) * nf,
+                                out_specs=out_specs, check_vma=False))
 
     def call(*args):
         with meshes.sharding_ctx(mesh):
             return jfn(*args)
 
     return call
+
+
+def _dense_factors(Ms, k: int, mesh: Mesh):
+    """Each (n,)*k factor on the mesh, row-sliced on cut axis 0 and
+    zero-padded to the shard multiple: a host array goes straight to its
+    slices (counted as ``obs.upload`` counts), a device array is
+    resharded in the join's own program.  No stack is formed."""
+    d = num_shards(mesh)
+    rows = _ceil_to(np.shape(Ms[0])[0], d)
+    sharding = NamedSharding(mesh, P(*(["data"] + [None] * (k - 1))))
+    out = []
+    for M in Ms:
+        if isinstance(M, jax.Array):
+            out.append(_pad_axis(jnp.asarray(M, jnp.float64), 0, rows))
+            continue
+        M = np.asarray(M, np.float64)
+        if M.shape[0] < rows:
+            M = np.pad(M, [(0, rows - M.shape[0])] + [(0, 0)] * (k - 1))
+        out.append(obs.upload(M, sharding=sharding, site="xla_factors"))
+    return out
 
 
 def sharded_dense_join(Ms, k: int, *, mesh: Mesh) -> float:
-    """The f64 dense join (factors already expanded + injectivity mask
-    appended, as ``lowering._eval_cutjoin`` builds them) sharded over
-    the first cut axis.  Pure XLA — no f32 chunking, so no guard needed;
-    f64 sums of integer counts are exact in any order, so this is
-    bit-for-bit with the single-device ``_join_reduce``."""
-    d = num_shards(mesh)
+    """The f64 dense join over factors expanded to the (n,)*k cut grid
+    (as ``lowering._eval_cutjoin`` builds them), sharded over the first
+    cut axis, summed over pairwise-distinct cut tuples only (the
+    injectivity mask, built in each shard; |cut| = 1 needs none).  Pure XLA — no f32
+    chunking, so no guard needed; f64 sums of integer counts are exact
+    in any order, so this is bit-for-bit with the single-device
+    ``_join_reduce``."""
     with _x64():
-        stack = jnp.stack([obs.upload(M, jnp.float64, site="xla_factors")
-                           for M in Ms])
-        stack = _pad_axis(stack, 1, _ceil_to(stack.shape[1], d))
-        return float(obs.readback(_dense_scalar_fn(mesh, k)(stack),
+        fn = _dense_fn(mesh, len(Ms), k, None)
+        return float(obs.readback(fn(*_dense_factors(Ms, k, mesh)),
                                   site="xla_result"))
-
-
-@functools.lru_cache(maxsize=None)
-def _dense_keep_fn(mesh: Mesh, k: int, keep: int):
-    """shard_map'd dense f64 keep-axis join (the ``xla-sharded-keep``
-    route): the caller's pre-masked (nf, n, ..., n) stack row-sliced on
-    cut axis 0, local Π-then-Σ over the reduced axes; keep == 0 means
-    the kept axis is the sharded one (each shard owns an output slice —
-    concatenate via out_specs), otherwise each shard holds a partial
-    output vector and the shards ``psum``."""
-    def local(stack):
-        red = tuple(a for a in range(k) if a != keep)
-        vec = jnp.sum(jnp.prod(stack, axis=0), axis=red)
-        return vec if keep == 0 else jax.lax.psum(vec, "data")
-
-    in_specs = (P(None, "data", *([None] * (k - 1))),)
-    out_specs = P("data") if keep == 0 else P(None)
-    jfn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False))
-
-    def call(*args):
-        with meshes.sharding_ctx(mesh):
-            return jfn(*args)
-
-    return call
 
 
 def sharded_dense_join_keep(Ms, k: int, *, keep: int,
                             mesh: Mesh) -> np.ndarray:
-    """The f64 dense keep-axis join (factors expanded + injectivity
-    mask appended, as ``lowering._eval_local`` builds them) sharded
-    over cut axis 0 — the mesh analogue of the ``_join_keep`` /
-    ``_join_keep3`` XLA oracles, for keep-axis joins whose
-    ``exact_block`` guard refused (previously a wholesale single-device
-    fallback).  Pure XLA, f64 integer sums — bit-for-bit with the
-    single-device oracle by the same argument as
-    ``sharded_dense_join``."""
+    """The f64 dense keep-axis join (factors expanded to the cut grid,
+    as ``lowering._eval_local`` builds them; pairwise-distinct cut
+    tuples only, as in ``sharded_dense_join``) sharded over cut axis 0 — the mesh analogue
+    of the ``_join_keep`` / ``_join_keep3`` XLA oracles, for keep-axis
+    joins whose ``exact_block`` guard refused.  Pure XLA, f64 integer
+    sums — bit-for-bit with the single-device oracle by the same
+    argument as ``sharded_dense_join``."""
     assert 0 <= keep < k
-    d = num_shards(mesh)
+    n = np.shape(Ms[0])[keep]
     with _x64():
-        stack = jnp.stack([obs.upload(M, jnp.float64, site="xla_factors")
-                           for M in Ms])
-        assert stack.ndim == k + 1
-        n = stack.shape[1 + keep]
-        stack = _pad_axis(stack, 1, _ceil_to(stack.shape[1], d))
-        out = _dense_keep_fn(mesh, k, keep)(stack)
-        out = obs.readback(out, site="xla_result")
+        fn = _dense_fn(mesh, len(Ms), k, keep)
+        out = obs.readback(fn(*_dense_factors(Ms, k, mesh)),
+                           site="xla_result")
         return np.asarray(out, np.float64)[:n]
 
 

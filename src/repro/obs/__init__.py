@@ -44,10 +44,10 @@ from __future__ import annotations
 
 from repro.obs import drift
 from repro.obs.metrics import REGISTRY, MetricsRegistry, StatsView
-from repro.obs.trace import (Span, Tracer, counter, fence, readback, span,
-                             upload)
+from repro.obs.trace import (Span, Tracer, counter, fence, note, readback,
+                             span, upload)
 
-__all__ = ["Tracer", "Span", "fence", "span", "upload", "readback",
+__all__ = ["Tracer", "Span", "fence", "note", "span", "upload", "readback",
            "MetricsRegistry", "StatsView", "REGISTRY", "drift", "counter",
            "gauge", "observe", "get", "snapshot", "dump", "reset"]
 

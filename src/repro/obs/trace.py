@@ -163,17 +163,33 @@ def counter(name: str, value: float = 1, **labels) -> float:
     return total
 
 
-def upload(x, dtype=None, *, site: str):
+def note(key: str, items) -> None:
+    """Extend the list attribute ``key`` of the innermost span of the
+    Tracer whose read is open on this thread, for code that does not hold
+    the Tracer (the sharded Contract's step descriptions); a no-op
+    without one."""
+    tracers = _state()[1]
+    current = tracers[-1].current() if tracers else None
+    if current is not None:
+        current.attrs.setdefault(key, []).extend(items)
+
+
+def upload(x, dtype=None, *, site: str, sharding=None):
     """``jnp.asarray(x, dtype)`` of a host array in a ``gpm.upload``
     span, its bytes counted as they cross (the converted copy's: an f64
-    host array uploaded as f32 moves 4 bytes an element).  A jax Array
+    host array uploaded as f32 moves 4 bytes an element); with a
+    ``sharding``, each device receives only its slice.  A jax Array
     (already on the device, or a tracer) passes through uncounted."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
     if isinstance(x, jax.Array):
         return jnp.asarray(x, dtype)
     with span("upload", site=site):
-        out = jnp.asarray(x, dtype)
+        if sharding is None:
+            out = jnp.asarray(x, dtype)
+        else:
+            out = jax.device_put(np.asarray(x, dtype), sharding)
     counter("transfer.h2d_bytes", out.nbytes, site=site)
     return out
 

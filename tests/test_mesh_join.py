@@ -86,15 +86,22 @@ def test_sharded_joins_match_single_device():
         assert dcj.sharded_cutjoin3(Ms, axes, n=n, mesh=mesh, block=b) == \\
             ops.cutjoin_reduce3(Ms, axes, n=n, block=b)
 
-        # dense fallback route: f64, no guard, big magnitudes welcome
+        # dense fallback route: f64, no guard, big magnitudes welcome;
+        # summed over pairwise-distinct cut tuples only
+        import itertools
         import jax, jax.numpy as jnp
         big = float(1 << 30)
         for n, k in ((33, 2), (17, 3)):
             Ms = [rng.integers(0, 3, size=(n,) * k).astype(np.float64)
                   * big for _ in range(2)]
+            mask = np.ones((n,) * k)
+            for a, b in itertools.combinations(range(k), 2):
+                shape = [1] * k
+                shape[a] = shape[b] = n
+                mask = mask * (1.0 - np.eye(n)).reshape(shape)
             with jax.enable_x64():
                 ref = float(jnp.sum(jnp.prod(jnp.stack(
-                    [jnp.asarray(M) for M in Ms]), axis=0)))
+                    [jnp.asarray(M) for M in Ms + [mask]]), axis=0)))
             assert dcj.sharded_dense_join(Ms, k, mesh=mesh) == ref, (n, k)
         print("OK")
     """)

@@ -128,9 +128,10 @@ def test_compiled_plan_contract_route_sharded():
 
 def test_sharded_dense_keep_join_matches_oracle():
     """``sharded_dense_join_keep`` (the guard-refusal keep-axis route)
-    against a plain-numpy oracle: k in {2, 3}, every keep axis,
-    divisible and padding n."""
+    against a plain-numpy oracle over pairwise-distinct cut tuples:
+    k in {2, 3}, every keep axis, divisible and padding n."""
     r = _run("""
+        import itertools
         import numpy as np
         from repro.distributed import cutjoin as dcj, meshes
 
@@ -140,7 +141,12 @@ def test_sharded_dense_keep_join_matches_oracle():
             for k in (2, 3):
                 Ms = [rng.integers(0, 5, size=(n,) * k).astype(np.float64)
                       for _ in range(2)]
-                stack = np.stack(Ms)
+                mask = np.ones((n,) * k)
+                for a, b in itertools.combinations(range(k), 2):
+                    shape = [1] * k
+                    shape[a] = shape[b] = n
+                    mask = mask * (1.0 - np.eye(n)).reshape(shape)
+                stack = np.stack(Ms + [mask])
                 for keep in range(k):
                     red = tuple(a + 1 for a in range(k) if a != keep)
                     ref = np.sum(np.prod(stack, axis=0), axis=tuple(
@@ -341,3 +347,231 @@ def test_shard_check_covers_contract_nodes():
     res2 = analysis.shard_check(cp.plan, info, 4, budget=1 << 27)
     assert not [d for d in res2.warnings
                 if d.code == "shard-budget-overflow"]
+
+
+# -- the step forms at SCALE 6-9, on four devices -----------------------------
+
+_GENERATORS = """
+    import importlib.util
+    import os
+
+    GRAPHS = os.path.join({root!r}, "benchmarks", "gpm", "graphs")
+
+    def generate(kind, params, seed):
+        spec = importlib.util.spec_from_file_location(
+            "gen_" + kind, os.path.join(GRAPHS, kind + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.generate(params, seed)
+
+    KRON = dict(edgefactor=16, A=0.57, B=0.19, C=0.19)
+"""
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+
+
+def _run4(code: str):
+    prelude = textwrap.dedent(_GENERATORS.format(root=os.path.abspath(ROOT)))
+    return _run(prelude + textwrap.dedent(code), devices=4)
+
+
+_STEP_FORMS = """
+    import jax
+    import numpy as np
+    from repro.core import homomorphism as H
+    from repro.core.counting import CountingEngine
+    from repro.core.motifs import motif_patterns
+    from repro.core.pattern import Pattern, clique
+    from repro.distributed import contract as C, meshes
+    from repro.graph.storage import Graph
+
+    mesh = meshes.data_mesh()
+    assert meshes.num_shards(mesh) == 4
+    pats = [p for p in motif_patterns(4) if p.m < 6] + [clique(3)]
+    pats += [Pattern(4, ((0, 1), (1, 2), (2, 3), (0, 3)), labels=(0, 1, 2, 0)),
+             Pattern(3, ((0, 1), (1, 2)), labels=(2, 0, 1))]
+    seen = set()
+    for kind, params, seed in (("kronecker", dict(KRON, SCALE=6), 3),
+                               ("urand", dict(SCALE=7, degree=4), 4),
+                               ("kronecker", dict(KRON, SCALE=9), 5)):
+        n, edges = generate(kind, params, seed)
+        labels = np.random.default_rng(seed).integers(0, 3, n)
+        g = Graph(n, edges, labels)
+        sh, ref = CountingEngine(g, mesh=mesh), CountingEngine(g)
+        degree = int(g.degrees.max())
+        with jax.enable_x64():
+            blocks, A = sh._blocks(), ref.A
+            for p in pats:
+                for free in ((), (0,), (0, 1)):
+                    for order in (H.greedy_plan(p, free), tuple(range(p.n))):
+                        steps = []
+                        got = C.sharded_hom(
+                            p, blocks, mesh=mesh, n=n, order=order,
+                            free=free, unary=sh._unary_blocks(p),
+                            max_degree=degree, steps=steps)
+                        want = H.hom_count(p, A, order=order, free=free,
+                                           unary=ref._unary_for(p))
+                        assert np.array_equal(np.asarray(got),
+                                              np.asarray(want)), \\
+                            (kind, sorted(p.edges), p.labels, free, order)
+                        for s in steps:
+                            seen.add(s["form"])
+                            # a pair step over pair and vertex factors
+                            # always takes the narrow form here: f64-psum
+                            # only where a factor or the output is wider
+                            lhs, rhs = s["spec"].split("->")
+                            if s["form"] == "f64-psum":
+                                assert max(map(len, lhs.split(",") + [rhs])) \\
+                                    >= 3, s
+    assert seen == {"int8-scatter", "vector-psum", "out-sharded-f64",
+                    "f64-psum"}, seen
+    print("OK")
+"""
+
+
+def test_sharded_step_forms_bit_equal_hom_count():
+    """Every step form, on Kronecker and Urand graphs of SCALE 6-9 and
+    on labelled patterns, for the 4-motif plan's patterns under both
+    the greedy and the identity elimination order: bit-for-bit the f64
+    ``hom_count`` over the dense adjacency."""
+    r = _run4(_STEP_FORMS)
+    assert "OK" in r.stdout, r.stdout + r.stderr
+
+
+_REFUSED = """
+    import jax
+    import numpy as np
+    from repro import obs
+    from repro.core.counting import CountingEngine
+    from repro.core.pattern import cycle
+    from repro.distributed import contract as C, meshes
+    from repro.graph.storage import Graph
+
+    mesh = meshes.data_mesh()
+    c5, order = cycle(5), (0, 1, 2, 3, 4)
+    # eliminating 0 then 1 leaves A^3 (2 <- 1 <- 0 <- 4), whose entries
+    # reach the largest degree squared: 128**2 or more with a hub of
+    # degree 128 or more (Kronecker SCALE 9), below it on the Urand graph
+    for kind, params, seed, refused in (
+            ("kronecker", dict(KRON, SCALE=9), 5, True),
+            ("urand", dict(SCALE=7, degree=4), 4, False)):
+        n, edges = generate(kind, params, seed)
+        g = Graph(n, edges)
+        assert (int(g.degrees.max()) >= 128) == refused
+        obs.reset()
+        sh = CountingEngine(g, mesh=mesh)
+        got = sh.hom(c5, order=order)
+        assert got == CountingEngine(g).hom(c5, order=order)
+        steps = obs.snapshot()["contract.steps"]
+        assert steps.get("form=f64-psum", 0) == (1 if refused else 0), steps
+        assert steps["form=int8-scatter"] == (2 if refused else 3), steps
+        # the narrow steps' bound follows the degree it is given: at the
+        # loosest one, n, the A^3 step is refused on both graphs
+        with jax.enable_x64():
+            rows = []
+            val = C.sharded_hom(c5, sh._blocks(), mesh=mesh, n=n,
+                                max_degree=n, order=order, steps=rows)
+        assert float(val) == got
+        assert [s["form"] for s in rows].count("f64-psum") == 1
+    print("OK")
+"""
+
+
+def test_refused_bound_takes_the_f64_psum_form():
+    """A step whose factor bound needs more than two int8 digit planes
+    (A^3 on a hub-heavy graph) takes the f64 ``psum`` form, is counted,
+    and stays exact; the same plan on a graph without hubs runs every
+    pair step narrow."""
+    r = _run4(_REFUSED)
+    assert "OK" in r.stdout, r.stdout + r.stderr
+
+
+_BRUTE = """
+    from repro import compiler
+    from repro.core.counting import CountingEngine, brute_force_edge_induced
+    from repro.core.motifs import motif_patterns
+    from repro.core.pattern import Pattern
+    from repro.distributed import meshes
+    from repro.graph.storage import Graph
+    import numpy as np
+
+    mesh = meshes.data_mesh()
+    labelled = [Pattern(4, ((0, 1), (1, 2), (2, 3), (0, 3)),
+                        labels=(0, 1, 0, 1)),
+                Pattern(4, ((0, 1), (1, 2), (2, 3)), labels=(2, 0, 1, 0))]
+    for kind, params, seed in (("kronecker", dict(KRON, SCALE=6), 6),
+                               ("urand", dict(SCALE=6, degree=4), 7)):
+        n, edges = generate(kind, params, seed)
+        g = Graph(n, edges, np.random.default_rng(seed).integers(0, 3, n))
+        eng = CountingEngine(g, mesh=mesh)
+        for pats in (motif_patterns(4), labelled):
+            cp = compiler.compile(pats, g, counter=eng, cache=False,
+                                  mesh=mesh)
+            for p in pats:
+                assert cp.count(p) == brute_force_edge_induced(g, p), \\
+                    (kind, sorted(p.edges), p.labels)
+        assert eng._A_dense is None
+    print("OK")
+"""
+
+
+def test_mesh_census_equals_brute_force():
+    """The 4-motif census and labelled patterns through
+    ``compile(mesh=)`` on four devices: integer-equal to brute force."""
+    r = _run4(_BRUTE)
+    assert "OK" in r.stdout, r.stdout + r.stderr
+
+
+_GUARD_REFUSED = """
+    import numpy as np
+    from repro import compiler, obs
+    from repro.api.local import plan_vertex_counts
+    from repro.compiler import lowering
+    from repro.core.counting import CountingEngine
+    from repro.core.motifs import motif_patterns
+    from repro.core.pattern import chain
+    from repro.distributed import meshes
+    from repro.graph.storage import Graph
+    from repro.kernels import ops
+
+    # every join's guard refuses, as Graph500's hubs make it refuse
+    ops.cutjoin_exact_block = lambda *a, **k: None
+    lowering.CompiledPlan._precertified = lambda self: {}
+    mesh = meshes.data_mesh()
+    n, edges = generate("kronecker", dict(KRON, SCALE=7), 8)
+    g = Graph(n, edges)
+    pats = motif_patterns(4)
+    tr = obs.Tracer()
+    cp = compiler.compile(pats, g, counter=CountingEngine(g, mesh=mesh),
+                          cache=False, mesh=mesh)
+    cp.tracer = tr
+    oracle = compiler.compile(pats, g, counter=CountingEngine(g),
+                              cache=False, cutjoin_kernel=False)
+    for p in pats:
+        assert cp.count(p) == oracle.count(p), sorted(p.edges)
+    joins = {s.attrs["route"] for s in tr.walk() if s.kind == "CutJoin"}
+    assert joins == {"xla-sharded"}, joins
+    assert not cp._masks                 # no host mask, built in the shards
+
+    p = chain(4)
+    tr = obs.Tracer()
+    cp = compiler.compile(p, g, counter=CountingEngine(g, mesh=mesh),
+                          cache=False, mesh=mesh, local=True)
+    cp.tracer = tr
+    oracle = compiler.compile(p, g, counter=CountingEngine(g), cache=False,
+                              local=True, cutjoin_kernel=False)
+    assert np.array_equal(plan_vertex_counts(cp, p),
+                          plan_vertex_counts(oracle, p))
+    routes = {s.attrs.get("route") for s in tr.walk()}
+    assert "xla-sharded-keep" in routes, routes
+    assert not cp._masks
+    print("OK")
+"""
+
+
+def test_guard_refused_mesh_join_matches_xla_dense_oracle():
+    """Joins whose ``exact_block`` guard refuses run on the mesh as the
+    f64 ``xla-sharded`` / ``xla-sharded-keep`` joins, their injectivity
+    mask built from iotas inside each shard (no host mask): equal to the
+    single-device ``xla-dense`` oracle."""
+    r = _run4(_GUARD_REFUSED)
+    assert "OK" in r.stdout, r.stdout + r.stderr
