@@ -12,10 +12,10 @@ Ops
                       pattern under an explicit vertex order; with ``free``
                       vertices it yields a tensor over graph vertices
                       (used by the decomposed path's per-subpattern counts).
-``Intersect``         the ordered-enumeration / set-intersection route for
-                      complete patterns (cliques have no cutting set,
-                      paper §2.4); lowers to degeneracy-ordered
-                      intersections or the Pallas triangle kernel.
+``Intersect``         the route for complete patterns (cliques have no
+                      cutting set, paper §2.4); lowers to one exact int8
+                      device pass for K3 and K4, degeneracy-ordered
+                      intersections, or the Pallas triangle kernel.
 ``MobiusCombine``     Σ coeff · hom(quotient) over the partition lattice
                       (inj when divisor == 1, embedding count when
                       divisor == |Aut|).
@@ -168,7 +168,8 @@ class Contract:
 
 @dataclass(frozen=True)
 class Intersect:
-    """hom(K_k) = k! · (# k-cliques) via ordered enumeration."""
+    """hom(K_k) = k! · (# k-cliques): the device pass for k <= 4, else
+    ordered enumeration."""
     key: str
     k: int
 

@@ -12,7 +12,9 @@ evaluates nodes on demand with per-node memoisation:
                     axis 0 and hand off to the sharded join tier without
                     a gather, and no unsharded n x n adjacency is ever
                     materialised;
-* ``Intersect``  -> degeneracy-ordered clique enumeration, or the Pallas
+* ``Intersect``  -> K3 and K4 by one exact int8 device pass over the
+                    edges (route ``clique-device``), larger cliques by
+                    degeneracy-ordered enumeration, or the Pallas
                     ``triangle_count`` kernel when ``use_pallas`` is set
                     (k == 3, f32 MXU path; inputs zero-padded to the tile
                     multiple, so any ``n`` works);
@@ -331,7 +333,7 @@ class CompiledPlan:
                 self._annotate(route="pallas-triangle")
                 adj = self.graph.dense_adjacency(np.float32, pad=False)
                 return 6.0 * float(ops.triangle_count(adj))
-            self._annotate(route="enumeration")
+            self._annotate(route=self.counter.clique_route(node.k))
             return self.counter.hom(clique(node.k))
         if isinstance(node, MobiusCombine):
             self._annotate(route="host")

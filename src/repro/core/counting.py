@@ -18,9 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core import cliques
 from repro.core import homomorphism as H
 from repro.core.motifs import motif_patterns
-from repro.core.pattern import Pattern, free_skeleton, mark_free
+from repro.core.pattern import Pattern, clique, free_skeleton, mark_free
 from repro.core.quotient import mobius, partitions, quotient_terms
 from repro.graph.storage import Graph
 
@@ -171,13 +172,8 @@ class CountingEngine:
         self.stats["hom_evals"] += 1
         if c.labels is None and c.m == c.n * (c.n - 1) // 2 and c.n >= 3:
             # complete pattern: no cutting set exists (paper §2.4) and the
-            # dense contraction needs an N^(k-2) intermediate — route to
-            # ordered enumeration.  hom(K_k) = k! * #cliques.
-            import math
-            from repro.core.cliques import clique_count
-            with obs.span("enumerate", k=c.n):
-                val = float(math.factorial(c.n)
-                            * clique_count(self.graph, c.n))
+            # dense contraction needs an N^(k-2) intermediate
+            val = self._clique_hom(c)
         elif self.mesh is not None:
             with self._x64():
                 val = float(obs.readback(
@@ -190,6 +186,29 @@ class CountingEngine:
                     site="contract"))
         self.hom_memo[c] = val
         return val
+
+    def clique_route(self, k: int) -> str:
+        """How hom(K_k) is counted on this graph: ``clique-device`` (one
+        exact int8 pass on the device gives K3 and K4 together) for
+        k <= 4 where int32 holds the pass's rows, else ``enumeration``
+        (ordered, on the host CSR)."""
+        return ("clique-device" if k in (3, 4)
+                and self.graph.n <= cliques.PASS_MAX_N else "enumeration")
+
+    def _clique_hom(self, c: Pattern) -> float:
+        """hom(K_k) = k! · #k-cliques, by the route ``clique_route``
+        names; the device pass memoises hom(K3) and hom(K4) both."""
+        route = self.clique_route(c.n)
+        obs.counter("cliques.passes",
+                    route="device" if route == "clique-device" else "host")
+        with obs.span("enumerate", k=c.n, route=route):
+            if route == "enumeration":
+                return float(math.factorial(c.n)
+                             * cliques.clique_count(self.graph, c.n))
+            homs = cliques.clique_homs(self.graph)
+        for k, val in zip((3, 4), homs):
+            self.hom_memo[clique(k).canonical()] = float(val)
+        return self.hom_memo[c]
 
     def hom_free_tensor(self, p: Pattern, free: tuple,
                         order=None) -> np.ndarray:

@@ -108,11 +108,40 @@ def test_golden_trace_shape_3cut():
     assert all(c.kind == "Contract" for c in join.children)
     assert all(c.attrs["route"] == "einsum-free" for c in join.children)
     assert [c.kind for c in mob.children] == ["Intersect"]
-    assert mob.children[0].attrs["route"] == "enumeration"
+    assert mob.children[0].attrs["route"] == "clique-device"
     # second read: everything memoised, no new spans
     n_before = sum(1 for _ in tr.walk())
     cp.count(K5_MINUS_EDGE)
     assert sum(1 for _ in tr.walk()) == n_before + 1    # just the root
+
+
+@pytest.fixture(scope="module")
+def clique_plan():
+    """One plan counting K3, K4 and K5, traced."""
+    g = erdos_renyi(20, 9.0, seed=2)
+    tr = obs.Tracer()
+    pats = [clique(3), clique(4), clique(5)]
+    cp = compiler.compile(pats, g, counter=CountingEngine(g), cache=False)
+    cp.tracer = tr
+    for p in pats:
+        cp.count(p)
+    return g, tr, cp
+
+
+@pytest.mark.parametrize("k,route", [(3, "clique-device"),
+                                     (4, "clique-device"),
+                                     (5, "enumeration")])
+def test_intersect_route_follows_clique_size(clique_plan, k, route):
+    """K3 and K4 Intersect nodes take the device pass; a larger clique
+    is still counted by host enumeration.  Either way the node's value
+    is k! times the enumerated cliques."""
+    import math
+    from repro.core.cliques import clique_count
+    g, tr, cp = clique_plan
+    (node,) = [s for s in tr.walk() if s.kind == "Intersect"
+               and cp.plan.nodes[s.name].k == k]
+    assert node.attrs["route"] == route
+    assert cp.value(node.name) == math.factorial(k) * clique_count(g, k)
 
 
 def test_trace_route_xla_dense_when_kernel_off():

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from repro import compiler, obs
+from repro.core import cliques
 from repro.core.motifs import motif_patterns
 from repro.graph.generators import erdos_renyi
 from repro.graph.storage import Graph
@@ -127,13 +128,19 @@ def test_idle_by_span_splits_a_gap_at_span_edges():
     assert ibs.per_job(ctx, "unattributed_idle_s") is None
 
 
-def _expected_transfers(tracer, plan, n: int):
+def _expected_transfers(tracer, plan, n: int, m: int):
     """(h2d, d2h) bytes of a count read, from the plan's shapes and the
     routes the tracer recorded: the f64 adjacency up once; each free
     Contract's f64 tensor and each scalar down; kernel joins' factors
     up as f32 and XLA joins' factors plus mask up as f64, each join's
-    scalar down."""
+    scalar down; for the clique pass, once however many cliques take
+    it, the m edges padded to whole chunks up as int32 pairs and two
+    int32 sums an edge down."""
     h2d, d2h = 8 * n * n, 0
+    if any(s.attrs.get("route") == "clique-device" for s in tracer.walk()):
+        pairs = cliques.CHUNK * -(-m // cliques.CHUNK)
+        h2d += 8 * pairs
+        d2h += 8 * pairs
     for s in tracer.walk():
         route = s.attrs.get("route")
         if s.kind == "Contract":
@@ -161,7 +168,7 @@ def test_transfer_bytes_equal_the_plans_shapes(kernel):
         cp.count(p)
     routes = {s.attrs["route"] for s in tracer.walk() if s.kind == "CutJoin"}
     assert routes == {"kernel" if kernel else "xla-dense"}
-    h2d, d2h = _expected_transfers(tracer, cp.plan, n)
+    h2d, d2h = _expected_transfers(tracer, cp.plan, n, g.m)
     assert tracer.total("transfer.h2d_bytes") == h2d
     assert tracer.total("transfer.d2h_bytes") == d2h
     assert tracer.to_dict()["counts"]["transfer.h2d_bytes"]
@@ -169,6 +176,7 @@ def test_transfer_bytes_equal_the_plans_shapes(kernel):
 
 _MESH_TRANSFERS = """
     from repro import compiler, obs
+    from repro.core import cliques
     from repro.core.counting import CountingEngine
     from repro.core.motifs import motif_patterns
     from repro.distributed import contract as C, meshes
@@ -179,6 +187,7 @@ _MESH_TRANSFERS = """
     g = erdos_renyi(96, 6.0, seed=5)
     pats = motif_patterns(4)
     rows = C.padded_rows(g.n, mesh)
+    pairs = cliques.CHUNK * -(-g.m // cliques.CHUNK)
     for kernel, route, site in ((True, "kernel-sharded", "kernel_result"),
                                 (False, "xla-sharded", "xla_result")):
         cp = compiler.compile(pats, g, counter=CountingEngine(g, mesh=mesh),
@@ -194,14 +203,18 @@ _MESH_TRANSFERS = """
                    and not cp.plan.nodes[s.name].free]
         d2h = tracer.counts["transfer.d2h_bytes"]
         h2d = tracer.counts["transfer.h2d_bytes"]
-        # every sharded join's scalar and every scalar Contract is read
-        # back and counted; the free Contract tensors stay on the mesh
+        # every sharded join's scalar, every scalar Contract and the
+        # clique pass's two int32 sums an edge are read back and counted;
+        # the free Contract tensors stay on the mesh
         assert d2h == {f"site={site}": 8.0 * len(joins),
-                       "site=contract": 8.0 * len(scalars)}, d2h
-        # the row-sharded adjacency goes up once, padded to the mesh
+                       "site=contract": 8.0 * len(scalars),
+                       "site=clique_rows": 8.0 * pairs}, d2h
+        # the row-sharded adjacency goes up once, padded to the mesh, and
+        # the clique pass's edges once, as int32 pairs
         assert h2d["site=adjacency"] == 8 * rows * rows, h2d
+        assert h2d["site=clique_edges"] == 8 * pairs, h2d
         assert set(h2d) <= {"site=adjacency", "site=kernel_factors",
-                            "site=xla_factors"}, h2d
+                            "site=xla_factors", "site=clique_edges"}, h2d
     print("OK")
 """
 

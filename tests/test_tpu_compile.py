@@ -6,7 +6,9 @@ for a certified exactness chunk below the lane width (8) and at it
 (128).  One f64 Contract einsum at that size must fit the chip's 16 GB.
 On the described v5e:2x2, every form of the sharded Contract step must
 fit one chip at n = 16,384 (Graph500 SCALE 14), the adjacency
-row-sharded over the four chips.  The topology is described inside a
+row-sharded over the four chips.  The K3/K4 clique pass at that n
+keeps its transient under two int8 n x n buffers.  The topology is
+described inside a
 fixture, never at import: only the worker that runs this file loads the
 TPU compiler.
 """
@@ -21,6 +23,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from repro.core import cliques
 from repro.distributed import contract as C
 from repro.kernels import matreduce as mr
 from repro.kernels import ops
@@ -188,3 +191,18 @@ def test_int8_scatter_step_at_scale15(four_chips, digits, fits):
     total = (m.temp_size_in_bytes + m.argument_size_in_bytes
              + m.output_size_in_bytes)
     assert (total < HBM_USABLE) == fits, total
+
+
+def test_clique_pass_transient_at_scale14(one_chip):
+    """The K3/K4 pass over Graph500 SCALE 14's 213,050 edges on one chip:
+    at most two int8 n x n buffers and 8 MiB for the edges, rows and
+    outputs, small beside the 0.537 GB f64 row shard each chip of the
+    mesh already holds."""
+    m = 213050
+    steps = -(-m // cliques.CHUNK)
+    chunks = _sds(one_chip, (steps, cliques.CHUNK, 2), jnp.int32)
+    compiled = cliques._clique_pass.lower(chunks, n=SCALE14).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert SCALE14 ** 2 <= total < 2 * SCALE14 ** 2 + 2 ** 23, total
