@@ -155,9 +155,8 @@ def _add_local_outputs(plan, patterns, graph, apct, budget, counter,
 def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
             apct=None, counter=None, cache: Optional[PlanCache] = None,
             budget: int = 1 << 27, max_cutjoin_cut: int = 3,
-            use_pallas: bool = False, cutjoin_kernel: bool = True,
-            domains: bool = False, local: bool = False,
-            verify: bool = True, mesh=None,
+            cutjoin_kernel: bool = True, domains: bool = False,
+            local: bool = False, verify: bool = True, mesh=None,
             morph=False) -> CompiledPlan:
     """Compile a pattern (or application pattern set) for one graph.
 
@@ -270,8 +269,7 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
                 mesh_devices=mesh_devices):
             if (not domains or plan.meta.get("domains")) \
                     and (not local or plan.meta.get("local")):
-                return lower(plan, graph, counter=counter,
-                             use_pallas=use_pallas, from_cache=True,
+                return lower(plan, graph, counter=counter, from_cache=True,
                              budget=budget, cutjoin_kernel=cutjoin_kernel,
                              mesh=mesh, count_store=morph_store)
             # config matches but the stored plan lacks a requested
@@ -310,8 +308,7 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
                 plan.meta["graph_info"] = ginfo.to_dict()
                 analysis.verify(plan, graph_info=ginfo,
                                 budget=budget).raise_if_failed()
-            return lower(plan, graph, counter=counter,
-                         use_pallas=use_pallas, from_cache=False,
+            return lower(plan, graph, counter=counter, from_cache=False,
                          budget=budget, cutjoin_kernel=cutjoin_kernel,
                          mesh=mesh, count_store=morph_store)
         for d in derived:
@@ -385,7 +382,6 @@ def compile(patterns: Union[Pattern, Iterable[Pattern]], graph: Graph, *,
         # morph-biased selections never enter the shared plan cache: a
         # later morph=False compile must see PR-9-identical behaviour
         cache.put(key, plan)
-    return lower(plan, graph, counter=counter, use_pallas=use_pallas,
-                 from_cache=False, budget=budget,
-                 cutjoin_kernel=cutjoin_kernel, mesh=mesh,
+    return lower(plan, graph, counter=counter, from_cache=False,
+                 budget=budget, cutjoin_kernel=cutjoin_kernel, mesh=mesh,
                  count_store=morph_store)

@@ -60,11 +60,12 @@ DENSE_TILE = CM.DENSE_TILE
 
 # how much cheaper one streamed kernel-tier tile is than one dense f64
 # gather-einsum tile: the CutJoin tiers run chunked f32 broadcast
-# multiplies through the VPU (measured ~4-10x over the XLA dense join,
-# see benchmarks/bench_cutjoin.py), while Contract floors model f64
-# einsum contractions — without the discount a tri join prices like a
-# fourth contraction and the model refuses decompositions that are
-# measurably faster end-to-end
+# multiplies through the VPU, while Contract floors model f64 einsum
+# contractions — without the discount a tri join prices like a fourth
+# contraction and the model refuses decompositions that are faster
+# end-to-end.  The value was fit to Pallas interpret-mode timings on a
+# CPU (the kernel ~4-10x over the XLA dense join) and has not been
+# recalibrated on a TPU yet
 KERNEL_STREAM_DISCOUNT = 4.0
 
 

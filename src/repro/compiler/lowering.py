@@ -14,10 +14,7 @@ evaluates nodes on demand with per-node memoisation:
                     materialised;
 * ``Intersect``  -> K3 and K4 by one exact int8 device pass over the
                     edges (route ``clique-device``), larger cliques by
-                    degeneracy-ordered enumeration, or the Pallas
-                    ``triangle_count`` kernel when ``use_pallas`` is set
-                    (k == 3, f32 MXU path; inputs zero-padded to the tile
-                    multiple, so any ``n`` works);
+                    degeneracy-ordered enumeration;
 * ``CutJoin``    -> the fused Pallas kernel tier for |cut| <= 3: the
                     k-factor masked product-reduce (``kernels.ops.
                     cutjoin_reduce``) for |cut| <= 2, the tiled tri-join
@@ -90,7 +87,7 @@ class CompiledPlan:
 
     def __init__(self, plan: Plan, graph: Graph,
                  counter: Optional[CountingEngine] = None,
-                 use_pallas: bool = False, from_cache: bool = False,
+                 from_cache: bool = False,
                  budget: int = 1 << 27, cutjoin_kernel: bool = True,
                  mesh=None, count_store=None):
         self.plan = plan
@@ -100,7 +97,6 @@ class CompiledPlan:
         # its own binding — pass mesh= to CountingEngine to shard it)
         self.counter = counter or CountingEngine(graph, budget=budget,
                                                  mesh=mesh)
-        self.use_pallas = use_pallas
         self.cutjoin_kernel = cutjoin_kernel
         self.from_cache = from_cache
         # execution mesh for the sharded tiers (a 1-D ("data",) jax
@@ -328,11 +324,6 @@ class CompiledPlan:
             if held is not None:
                 self._annotate(route="morph-derive")
                 return float(held)
-            if self.use_pallas and node.k == 3:
-                from repro.kernels import ops
-                self._annotate(route="pallas-triangle")
-                adj = self.graph.dense_adjacency(np.float32, pad=False)
-                return 6.0 * float(ops.triangle_count(adj))
             self._annotate(route=self.counter.clique_route(node.k))
             return self.counter.hom(clique(node.k))
         if isinstance(node, MobiusCombine):
@@ -378,7 +369,7 @@ class CompiledPlan:
                 if any(isinstance(v, jax.Array) for _, v in vals):
                     # from the first term, keeping its sharding: a zeros
                     # start would first be whole on one device
-                    with self.counter._x64():
+                    with jax.enable_x64():
                         M = None
                         for coeff, v in vals:
                             term = coeff * jnp.asarray(v, jnp.float64)
@@ -403,7 +394,7 @@ class CompiledPlan:
             if not np.size(M):
                 v = 0.0
             elif isinstance(M, jax.Array):
-                with self.counter._x64():
+                with jax.enable_x64():
                     v = float(jnp.max(jnp.abs(M)))
             else:
                 v = float(np.abs(np.asarray(M)).max())
@@ -558,7 +549,7 @@ class CompiledPlan:
         if shards > 1:
             self._shard_fallback("wide-cut")
         self._annotate(route="xla-dense")
-        with obs.span("join", route="xla-dense"), self.counter._x64():
+        with obs.span("join", route="xla-dense"), jax.enable_x64():
             stack = jnp.stack([obs.upload(M, site="xla_factors")
                                for M in Ms])
             return float(obs.readback(_join_reduce(stack),
@@ -637,7 +628,7 @@ class CompiledPlan:
             self._annotate(route="xla-keep")
             with obs.span("expand", cut=node.cut_size):
                 dense = self._dense_expand(Ms, axes, node.cut_size)
-            with obs.span("join", route="xla-keep"), self.counter._x64():
+            with obs.span("join", route="xla-keep"), jax.enable_x64():
                 stack = jnp.stack([obs.upload(M, site="xla_factors")
                                    for M in dense])
                 if node.cut_size == 2:
@@ -683,10 +674,10 @@ class CompiledPlan:
         return self._masks[k]
 
 
-def lower(plan: Plan, graph: Graph, *, counter=None, use_pallas=False,
-          from_cache=False, budget: int = 1 << 27,
-          cutjoin_kernel: bool = True, verify: bool = False,
-          mesh=None, count_store=None) -> CompiledPlan:
+def lower(plan: Plan, graph: Graph, *, counter=None, from_cache=False,
+          budget: int = 1 << 27, cutjoin_kernel: bool = True,
+          verify: bool = False, mesh=None,
+          count_store=None) -> CompiledPlan:
     """Bind a plan to a graph.  ``verify=True`` runs the static
     verifier against this graph first and raises ``PlanVerifyError``
     instead of binding a malformed plan — for plans that arrived from
@@ -701,7 +692,6 @@ def lower(plan: Plan, graph: Graph, *, counter=None, use_pallas=False,
         analysis.verify(
             plan, graph_info=analysis.GraphInfo.from_graph(graph),
             budget=budget).raise_if_failed()
-    return CompiledPlan(plan, graph, counter=counter, use_pallas=use_pallas,
-                        from_cache=from_cache, budget=budget,
-                        cutjoin_kernel=cutjoin_kernel, mesh=mesh,
-                        count_store=count_store)
+    return CompiledPlan(plan, graph, counter=counter, from_cache=from_cache,
+                        budget=budget, cutjoin_kernel=cutjoin_kernel,
+                        mesh=mesh, count_store=count_store)

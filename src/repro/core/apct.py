@@ -14,8 +14,6 @@ estimation").
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from repro.core.motifs import motif_patterns
@@ -99,12 +97,10 @@ class APCT:
                                                          seed=seed)
         self.table: dict = {}
         self.misses = 0
-        t0 = time.perf_counter()
         for k in range(2, max_size + 1):
             for p in motif_patterns(k):
                 self.table[p] = estimate_inj(self.profile_graph, p,
                                              num_samples, seed)
-        self.profile_time_s = time.perf_counter() - t0
 
     def query(self, p: Pattern) -> float:
         c = p.canonical()

@@ -110,12 +110,3 @@ def plan_cost_automine(p: Pattern, order, n: int, avg_degree: float) -> float:
         total += trips
         done |= front
     return total
-
-
-def pattern_cost_automine(p: Pattern, cut, n: int, avg_degree: float) -> float:
-    total = 0.0
-    for coeff, q in quotient_terms(p):
-        order = (H.plan_from_cut(q, _cut_image(p, cut, q))
-                 if cut else H.greedy_plan(q))
-        total += plan_cost_automine(q, order, n, avg_degree)
-    return total

@@ -46,13 +46,9 @@ def _quotient_order(q: Pattern, cut_blocks: frozenset | None):
 class CountingEngine:
     """Tensorised counting over one input graph."""
 
-    def __init__(self, graph: Graph, budget: int = 1 << 27,
-                 use_x64: bool = True, mesh=None):
+    def __init__(self, graph: Graph, budget: int = 1 << 27, mesh=None):
         self.graph = graph
         self.budget = budget
-        self.use_x64 = use_x64
-        self._x64 = jax.enable_x64 if use_x64 else _nullctx
-        self._np_dtype = np.float64 if use_x64 else np.float32
         # sharded-contraction binding: a 1-D ("data",) mesh routes hom /
         # hom_free_tensor through ``distributed.contract`` (row-sharded
         # adjacency, collective einsums — bit-for-bit with the
@@ -83,9 +79,9 @@ class CountingEngine:
         contractions all run sharded (or clique-enumerated) never pay
         for or hold it."""
         if self._A_dense is None:
-            with self._x64():
+            with jax.enable_x64():
                 self._A_dense = obs.upload(
-                    self.graph.dense_adjacency(self._np_dtype, pad=False),
+                    self.graph.dense_adjacency(np.float64, pad=False),
                     site="adjacency")
         return self._A_dense
 
@@ -96,9 +92,9 @@ class CountingEngine:
         if self.graph.labels is None:
             return None
         if self._labels_dense is None:
-            with self._x64():
+            with jax.enable_x64():
                 self._labels_dense = obs.upload(
-                    self.graph.label_indicators(self._np_dtype, pad=False),
+                    self.graph.label_indicators(np.float64, pad=False),
                     site="labels")
         return self._labels_dense
 
@@ -114,10 +110,10 @@ class CountingEngine:
     def _blocks(self):
         if self._A_blocks is None:
             from repro.distributed import contract as C
-            with self._x64():
+            with jax.enable_x64():
                 self._A_blocks = _sharded_upload(
                     C.adjacency_blocks, self.graph, self.mesh,
-                    self._np_dtype, site="adjacency")
+                    np.float64, site="adjacency")
         return self._A_blocks
 
     def _unary_blocks(self, p: Pattern):
@@ -126,10 +122,10 @@ class CountingEngine:
         if p.labels is None or self.graph.labels is None:
             return None
         from repro.distributed import contract as C
-        with self._x64():
+        with jax.enable_x64():
             if self._label_rows is None:
                 self._label_rows = _sharded_upload(
-                    C.label_blocks, self.graph, self.mesh, self._np_dtype,
+                    C.label_blocks, self.graph, self.mesh, np.float64,
                     site="labels")
             L = self._label_rows.shape[0]
             zero = jnp.zeros_like(self._label_rows[0])
@@ -175,12 +171,12 @@ class CountingEngine:
             # dense contraction needs an N^(k-2) intermediate
             val = self._clique_hom(c)
         elif self.mesh is not None:
-            with self._x64():
+            with jax.enable_x64():
                 val = float(obs.readback(
                     self._sharded_contract(c, order=order),
                     site="contract"))
         else:
-            with self._x64():
+            with jax.enable_x64():
                 val = float(obs.readback(
                     self._contract(c, order=order, unary=self._unary_for(c)),
                     site="contract"))
@@ -230,12 +226,12 @@ class CountingEngine:
             return self.hom_free_memo[key]
         self.stats["hom_evals"] += 1
         if self.mesh is not None:
-            with self._x64():
+            with jax.enable_x64():
                 val = self._sharded_contract(
                     p, order=tuple(order) if order else None,
                     free=tuple(free))
         else:
-            with self._x64():
+            with jax.enable_x64():
                 val = obs.readback(self._contract(
                     p, order=tuple(order) if order else None,
                     free=tuple(free), unary=self._unary_for(p)),
@@ -335,7 +331,7 @@ class CountingEngine:
         must map to edges AND non-edges to non-edges.  Zero-diagonal
         factors enforce injectivity automatically.  Exponential in pattern
         size — test oracle only."""
-        with self._x64():
+        with jax.enable_x64():
             comp = (1.0 - self.A) - jnp.eye(self.A.shape[0], dtype=self.A.dtype)
             et = {}
             full = []
@@ -369,14 +365,6 @@ class CountingEngine:
 
     def existence(self, p: Pattern) -> bool:
         return self.inj(p) > 0.5
-
-
-class _nullctx:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
 
 
 # -- overlay transform ----------------------------------------------------------

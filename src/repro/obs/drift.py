@@ -22,12 +22,9 @@ per-device collective term (``costing._kernel_join_cost(devices=)``,
   (the autotune on-ramp); a wide spread means the class's cost formula
   is structurally wrong, not just unscaled.
 
-Consumes either trace-tree JSON (``Tracer.to_json``) or the
-``drift_pairs`` table ``benchmarks/bench_obs.py`` embeds in
-``BENCH_obs.json``:
+Consumes trace-tree JSON (``Tracer.to_json``):
 
     python -m repro.obs.drift out.json
-    python -m repro.obs.drift benchmarks/results/BENCH_obs.json
 
 Stdlib-only on purpose — it must run anywhere a trace file lands.
 """
@@ -145,14 +142,6 @@ def aggregate(pairs: List[dict]) -> dict:
             "groups": out_groups}
 
 
-def bench_summary(report: dict) -> dict:
-    """Compact per-group summary for ``BENCH_obs.json``'s ``drift`` key
-    (what ``render_trend`` folds into the cross-commit table)."""
-    return {key: {"n": g["n"], "rank_corr": g["rank_corr"],
-                  "ratio_spread": g["ratio_spread"]}
-            for key, g in report["groups"].items()}
-
-
 def render(report: dict) -> str:
     """Human-readable calibration table."""
     lines = ["# Cost-model drift report",
@@ -173,22 +162,18 @@ def _fmt(v) -> str:
 
 
 def load_pairs(path: str) -> List[dict]:
-    """Pairs from one file: a ``BENCH_obs.json`` (embedded
-    ``drift_pairs``) or a trace-tree JSON (``spans``)."""
+    """Pairs from one trace-tree JSON (``spans``)."""
     with open(path) as fh:
         d = json.load(fh)
-    if "drift_pairs" in d:
-        return list(d["drift_pairs"])
     if "spans" in d:
         return pairs_from_trace(d)
-    raise ValueError(f"{path}: neither a trace (spans) nor a bench "
-                     f"result (drift_pairs)")
+    raise ValueError(f"{path}: not a trace (no spans)")
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("files", nargs="+",
-                    help="trace JSONs and/or BENCH_obs.json files")
+                    help="trace JSONs")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of a table")
     args = ap.parse_args(argv)

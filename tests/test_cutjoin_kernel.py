@@ -9,7 +9,7 @@ import pytest
 from repro.compiler import frontend, lowering
 from repro.core.counting import CountingEngine, brute_force_edge_induced
 from repro.core.decomposition import cutting_sets
-from repro.core.pattern import Pattern, chain, clique, cycle, tailed_triangle
+from repro.core.pattern import Pattern, chain, cycle, tailed_triangle
 from repro.graph.generators import erdos_renyi, triangle_rich
 from repro.kernels import ops
 
@@ -174,19 +174,6 @@ def test_costing_zero_costs_materialised_free_homs():
     assert costing.node_cost(node, apct, g.n, counter=eng) == 0.0
     # without the engine threaded in, the memo is invisible
     assert costing.node_cost(node, apct, g.n) == cold
-
-
-# -- use_pallas triangle tier: non-multiple n regression --------------------------
-
-@pytest.mark.parametrize("n", [150, 200])
-def test_use_pallas_triangle_non_multiple_n(n):
-    """Regression: the Pallas Intersect tier zero-pads to the tile
-    multiple, so any n works and padding is count-preserving."""
-    from repro import compiler
-    g = erdos_renyi(n, 6.0, seed=4)
-    assert g.n % 128 != 0
-    cp = compiler.compile((clique(3),), g, cache=False, use_pallas=True)
-    assert cp.count(clique(3)) == CountingEngine(g).edge_induced(clique(3))
 
 
 def test_matreduce_direct_call_pads():

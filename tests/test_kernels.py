@@ -49,6 +49,20 @@ def test_triangle_count_kernel_matches_engine():
     assert abs(got - want) < 1e-3
 
 
+@pytest.mark.parametrize("n", [150, 200])
+def test_triangle_count_pads_non_tile_multiple_n(n):
+    """The wrapper zero-pads to the tile multiple, so any n works and
+    padding is count-preserving."""
+    from repro.core.counting import CountingEngine
+    from repro.core.pattern import clique
+    from repro.graph.generators import erdos_renyi
+    g = erdos_renyi(n, 6.0, seed=4)
+    assert g.n % 128 != 0
+    adj = g.dense_adjacency(np.float32, pad=False)
+    got = float(ops.triangle_count(adj, interpret=True))
+    assert got == CountingEngine(g).edge_induced(clique(3))
+
+
 @pytest.mark.parametrize("E,W", [(256, 4), (512, 16), (64, 7)])
 def test_bitset_intersect_matches_ref(E, W):
     a = RNG.integers(0, 2**32, size=(E, W), dtype=np.uint32)

@@ -14,6 +14,7 @@ from repro.compiler import costing, frontend
 from repro.compiler import morph as morphlib
 from repro.compiler.cache import PlanCache, graph_signature, plan_key
 from repro.compiler.ir import pattern_key
+from repro.core.counting import CountingEngine
 from repro.core.pattern import Pattern, chain, clique, cycle
 from repro.core.quotient import quotient_terms
 from repro.graph import generators as gen
@@ -306,6 +307,33 @@ def test_fast_path_serves_family_member_without_search():
     assert obs.get("morph.hits", 0.0) == hits0 + 1
     direct = compiler.compile((chain(3),), g, cache=False).count(chain(3))
     assert cp.count(chain(3)) == direct
+
+
+def test_warm_store_serves_size4_family_exactly():
+    """Three 5-vertex plans warm the store: the 5-path, the gem and the
+    tailed 4-cycle.  Every size-4 motif compiled against it counts what
+    the engine counts directly, and ``morph.hits`` counts exactly the
+    members served algebraically."""
+    from repro.core.apct import APCT
+    g = gen.erdos_renyi(48, 5.0, seed=3)
+    apct = APCT(g, num_samples=512)
+    store = morphlib.CountStore()
+    gem = Pattern(5, [(0, 1), (1, 2), (2, 3), (0, 4), (1, 4), (2, 4),
+                      (3, 4)])
+    tailed_c4 = Pattern(5, [(0, 1), (1, 2), (2, 3), (0, 3), (3, 4)])
+    for p in (chain(5), gem, tailed_c4):
+        compiler.compile((p,), g, apct=apct, cache=False,
+                         morph=store).count(p)
+    hits0 = obs.get("morph.hits", 0.0)
+    family = morphlib.motif_family(4)
+    eng = CountingEngine(g)
+    served = 0
+    for p in family:
+        cp = compiler.compile((p,), g, apct=apct, cache=False, morph=store)
+        served += bool(cp.plan.meta.get("morph"))
+        assert cp.count(p) == eng.edge_induced(p), sorted(p.edges)
+    assert obs.get("morph.hits", 0.0) - hits0 == served
+    assert 2 * served >= len(family), served
 
 
 def test_missing_counts_fall_back_to_search():

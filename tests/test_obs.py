@@ -162,6 +162,65 @@ def test_trace_coverage_and_self_time():
         assert abs(s.self_s - max(0.0, s.duration_s - child_total)) < 1e-12
 
 
+def test_tracing_leaves_counts_unchanged():
+    """A plan re-evaluated with a tracer attached returns the count it
+    returned untraced, bit for bit."""
+    cp = compiler.compile(cycle(4), G, counter=CountingEngine(G),
+                          cache=False)
+    untraced = cp.count(cycle(4))
+    cp._values.clear()
+    cp.tracer = obs.Tracer()
+    assert cp.count(cycle(4)) == untraced
+    assert any(s.kind == "CutJoin" for s in cp.tracer.walk())
+
+
+# one traced run per plan shape: together they execute every node class
+# the compiler emits, and CutJoins of |cut| 2 and 3
+SWEEP = {"cycle4-2cut": ((cycle(4),), False),
+         "k5me-3cut": ((K5_MINUS_EDGE,), False),
+         "chain5-mobius": ((chain(5),), False),
+         "local-anchored": ((cycle(4), chain(4)), True)}
+
+
+@pytest.fixture(scope="module")
+def sweep_traces():
+    traces = {}
+    for label, (pats, local) in SWEEP.items():
+        tr = obs.Tracer(meta={"run": label})
+        cp = compiler.compile(pats, G, counter=CountingEngine(G),
+                              cache=False, local=local)
+        cp.tracer = tr
+        for p in pats:
+            cp.count(p)
+            if local:
+                for orbit in p.vertex_orbits():
+                    if cp.has_local(p, orbit[0]):
+                        cp.local_counts(p, orbit[0])
+                cp.exists(p)
+        traces[label] = tr
+    return traces
+
+
+@pytest.mark.parametrize("label", sorted(SWEEP))
+def test_sweep_trace_coverage(sweep_traces, label):
+    """Each plan shape's node spans explain >= 95% of its reads' wall
+    time, local reads and existence checks included."""
+    cov = sweep_traces[label].coverage()
+    assert cov is not None and cov >= 0.95, (label, cov)
+
+
+def test_drift_report_covers_every_node_class(sweep_traces):
+    """No node class drops out of calibration: the sweep's report has a
+    group for each, and CutJoin groups at |cut| 2 and 3."""
+    pairs = [pr for tr in sweep_traces.values()
+             for pr in pairs_from_trace(tr.to_dict())]
+    groups = sorted(aggregate(pairs)["groups"])
+    for cls in obs.drift.NODE_KINDS:
+        assert any(k.startswith(cls + "|") for k in groups), (cls, groups)
+    assert "CutJoin|cut=2|kernel" in groups, groups
+    assert "CutJoin|cut=3|kernel" in groups, groups
+
+
 def test_span_nesting_matches_ir_structure():
     """Property: the trace tree is a subtree of the plan DAG — every
     node span's children are refs of that node, and every root's single
@@ -298,11 +357,9 @@ def test_drift_pairs_and_aggregate():
     assert set(report["groups"]) == keys
     for g in report["groups"].values():
         assert g["n"] >= 1 and g["predicted_sum"] >= 0.0
-    # rendering and the bench summary never throw on real reports
+    # rendering never throws on real reports
     text = obs.drift.render(report)
     assert "CutJoin|cut=3|kernel" in text
-    summary = obs.drift.bench_summary(report)
-    assert set(summary) == keys
 
 
 def test_drift_aggregate_synthetic():

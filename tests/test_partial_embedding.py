@@ -202,6 +202,21 @@ def test_keep_axis_kernel_through_lowering_bitforbit():
     assert np.array_equal(a, CountingEngine(g).inj_free(p, 0))
 
 
+@pytest.mark.parametrize("p", [chain(4), tailed_triangle(), cycle(5)],
+                         ids=["chain4", "tailed-tri", "cycle5"])
+def test_compiled_anchored_counts_match_direct_route(p):
+    """Every orbit's anchored vector read off one ``local=True`` plan
+    equals the flat Möbius route's, on a fresh engine."""
+    g = GRAPHS["er"]
+    cp = compiler.compile((p,), g, counter=CountingEngine(g), cache=False,
+                          local=True)
+    for orbit in p.vertex_orbits():
+        direct = local_counts(p, g, anchor=orbit[0],
+                              counter=CountingEngine(g),
+                              use_compiler=False).counts
+        assert np.array_equal(cp.local_counts(p, orbit[0]), direct), orbit
+
+
 def test_exact_guard_falls_back_to_xla():
     """Factors beyond the f32 chunk guard must still evaluate exactly
     (the keep-axis path falls through to the f64 XLA join)."""

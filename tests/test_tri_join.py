@@ -245,6 +245,19 @@ def test_compile_commits_tri_plan_and_matches_direct():
     assert cp.count(p) == want and want > 0
 
 
+def test_max_cutjoin_cut_2_counts_like_the_tri_plan():
+    """Capped at |cut| <= 2, a pattern whose only cutting set has three
+    vertices compiles without a 3-cut join and counts what the tri plan
+    counts."""
+    g = erdos_renyi(18, 9.0, seed=3)
+    p = K5_MINUS_EDGE
+    capped = compiler.compile((p,), g, cache=False, max_cutjoin_cut=2)
+    assert not any(isinstance(n, CutJoin) and n.cut_size == 3
+                   for n in capped.plan.nodes.values())
+    tri = compiler.compile((p,), g, cache=False)
+    assert capped.count(p) == tri.count(p) > 0
+
+
 # -- golden IR locks ----------------------------------------------------------------
 
 def test_golden_tri_plan_six_cycle():
