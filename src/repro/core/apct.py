@@ -1,22 +1,23 @@
 """Approximate Pattern Count Table (paper §4.2).
 
 Dataset profiling: random-edge-sample the input graph down to E' edges,
-then estimate the count of every connected pattern up to 5 vertices with
-ASAP-style neighbour sampling (Fig 21, generalised to arbitrary patterns
-by sampling a BFS spanning tree and checking the non-tree edges).  The
-estimator is unbiased for injective-tuple counts; frequent patterns
-converge fast, infrequent ones are under-estimated — which is exactly the
-property the cost model needs (frequent subpatterns are the expensive
-contractions).
+then estimate the count of a connected pattern with ASAP-style neighbour
+sampling (Fig 21, generalised to arbitrary patterns by sampling a BFS
+spanning tree and checking the non-tree edges).  The estimator is
+unbiased for injective-tuple counts; frequent patterns converge fast,
+infrequent ones are under-estimated — which is exactly the property the
+cost model needs (frequent subpatterns are the expensive contractions).
 
-Misses are computed on demand and inserted (paper: "generated during cost
-estimation").
+The table is filled on demand: each pattern the cost model queries is
+estimated once and inserted (paper: "generated during cost
+estimation").  Every estimate draws from its own ``default_rng(seed)``,
+so its value does not depend on when, or in what order, it is computed.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from repro.core.motifs import motif_patterns
+from repro import obs
 from repro.core.pattern import Pattern
 from repro.graph.storage import Graph
 
@@ -41,8 +42,8 @@ def _bfs_tree(p: Pattern):
 
 def estimate_inj(g: Graph, p: Pattern, num_samples: int = 32_768,
                  seed: int = 0) -> float:
-    """Unbiased estimate of injective-tuple count of p in g (vectorised
-    neighbour sampling)."""
+    """Unbiased estimate of injective-tuple count of p in g (neighbour
+    sampling, vectorised over the samples)."""
     if g.m == 0 or p.n > g.n:
         return 0.0
     rng = np.random.default_rng(seed)
@@ -69,16 +70,16 @@ def estimate_inj(g: Graph, p: Pattern, num_samples: int = 32_768,
     for i in range(p.n):
         for j in range(i + 1, p.n):
             valid &= verts[i] != verts[j]
-    # non-tree edges
+    # non-tree edges: (a, b) is an edge iff the key a*n + b is among the
+    # CSR's keys row*n + nbr, strictly increasing since rows are sorted
     tree = {(min(v, parent[v]), max(v, parent[v])) for v in order[1:]}
-    for (u, v) in p.edges - tree:
-        a, b = verts[u], verts[v]
-        lo, hi = offs[a], offs[a + 1]
-        # vectorised membership: searchsorted within each row
-        pos = np.array([np.searchsorted(nbrs[l:h], x)
-                        for l, h, x in zip(lo, hi, b)])
-        found = (lo + pos < hi) & (nbrs[np.minimum(lo + pos, len(nbrs) - 1)] == b)
-        valid &= found
+    non_tree = p.edges - tree
+    if non_tree:
+        keys = np.repeat(np.arange(g.n, dtype=np.int64), deg) * g.n + nbrs
+        for (u, v) in non_tree:
+            want = verts[u] * g.n + verts[v]
+            pos = np.minimum(np.searchsorted(keys, want), len(keys) - 1)
+            valid &= keys[pos] == want
     # labels
     if g.labels is not None and p.labels is not None:
         for v in range(p.n):
@@ -90,17 +91,13 @@ class APCT:
     """The table: canonical pattern -> approximate injective-tuple count."""
 
     def __init__(self, graph: Graph, max_profile_edges: int = 100_000,
-                 num_samples: int = 32_768, max_size: int = 5, seed: int = 0):
+                 num_samples: int = 32_768, seed: int = 0):
         self.num_samples = num_samples
         self.seed = seed
         self.profile_graph = graph.subgraph_sample_edges(max_profile_edges,
                                                          seed=seed)
         self.table: dict = {}
-        self.misses = 0
-        for k in range(2, max_size + 1):
-            for p in motif_patterns(k):
-                self.table[p] = estimate_inj(self.profile_graph, p,
-                                             num_samples, seed)
+        self.misses = 0                   # entries estimated on demand
 
     def query(self, p: Pattern) -> float:
         c = p.canonical()
@@ -110,6 +107,7 @@ class APCT:
             c = Pattern(c.n, c.edges).canonical()
         if c not in self.table:
             self.misses += 1
+            obs.counter("apct.estimates")
             self.table[c] = estimate_inj(self.profile_graph, c,
                                          self.num_samples, self.seed)
         return self.table[c]

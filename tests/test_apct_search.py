@@ -6,10 +6,10 @@ import pytest
 
 from repro.core import cost_model as CM
 from repro.core import search as S
-from repro.core.apct import APCT, estimate_inj
+from repro.core.apct import APCT, _bfs_tree, estimate_inj
 from repro.core.counting import CountingEngine
 from repro.core.motifs import motif_patterns
-from repro.core.pattern import chain, clique
+from repro.core.pattern import Pattern, chain, clique
 from repro.graph.generators import erdos_renyi, triangle_rich
 
 G = triangle_rich(120, 8, seed=4)
@@ -88,3 +88,96 @@ def test_automine_model_underestimates_clustered_graphs(apct):
     exact_k4 = eng.inj(clique(4))
     if exact_k4 > 0:
         assert ours > am
+
+
+# -- the estimator's edge test and the lazily filled table ------------------
+
+PATTERNS_2_TO_5 = [p for k in range(2, 6) for p in motif_patterns(k)]
+
+
+def _estimate_by_rows(g, p, num_samples, seed):
+    """``estimate_inj`` with the non-tree edges tested row by row: one
+    ``searchsorted`` per sample within the CSR row of its first end."""
+    if g.m == 0 or p.n > g.n:
+        return 0.0
+    rng = np.random.default_rng(seed)
+    offs, nbrs = g.csr
+    deg = np.diff(offs)
+    order, parent = _bfs_tree(p)
+    verts = np.zeros((p.n, num_samples), np.int64)
+    weight = np.full(num_samples, float(g.n))
+    valid = np.ones(num_samples, bool)
+    verts[order[0]] = rng.integers(0, g.n, num_samples)
+    for v in order[1:]:
+        par = verts[parent[v]]
+        d = deg[par]
+        valid &= d > 0
+        d_safe = np.maximum(d, 1)
+        pick = (rng.random(num_samples) * d_safe).astype(np.int64)
+        verts[v] = nbrs[np.minimum(offs[par] + pick, len(nbrs) - 1)]
+        weight *= d_safe
+    for i in range(p.n):
+        for j in range(i + 1, p.n):
+            valid &= verts[i] != verts[j]
+    tree = {(min(v, parent[v]), max(v, parent[v])) for v in order[1:]}
+    for (u, v) in p.edges - tree:
+        a, b = verts[u], verts[v]
+        lo, hi = offs[a], offs[a + 1]
+        pos = np.array([np.searchsorted(nbrs[l:h], x)
+                        for l, h, x in zip(lo, hi, b)])
+        valid &= (lo + pos < hi) & \
+            (nbrs[np.minimum(lo + pos, len(nbrs) - 1)] == b)
+    if g.labels is not None and p.labels is not None:
+        for v in range(p.n):
+            valid &= g.labels[verts[v]] == np.array(p.labels[v])
+    return float(np.sum(weight * valid) / num_samples)
+
+
+@pytest.mark.parametrize("seed", [0, 7])
+@pytest.mark.parametrize("labelled", [False, True])
+@pytest.mark.parametrize("p", PATTERNS_2_TO_5, ids=repr)
+def test_edge_test_matches_row_search(p, labelled, seed):
+    """One search over the CSR's global keys decides every non-tree edge
+    exactly as the per-row search does, valid and invalid samples alike,
+    so the estimate is the same float."""
+    g = triangle_rich(90, 6, seed=seed, num_labels=2 if labelled else 0)
+    if labelled:
+        p = Pattern(p.n, p.edges, tuple(v % 2 for v in range(p.n)))
+    assert estimate_inj(g, p, 1024, seed) == \
+        _estimate_by_rows(g, p, 1024, seed)
+
+
+@pytest.fixture(scope="module")
+def lazy_apct():
+    return APCT(G, max_profile_edges=200, num_samples=4096, seed=3)
+
+
+@pytest.mark.parametrize("p", PATTERNS_2_TO_5, ids=repr)
+def test_lazy_query_is_the_eager_estimate(lazy_apct, p):
+    """A lazily filled entry is exactly what profiling it up front gave:
+    the same estimator on the same profile graph with the same seed."""
+    assert lazy_apct.profile_graph.m == 200 < G.m
+    want = estimate_inj(lazy_apct.profile_graph, p.canonical(), 4096, 3)
+    assert lazy_apct.query(p) == want
+    assert lazy_apct.query(p.relabel(tuple(reversed(range(p.n))))) == want
+
+
+def test_motif4_compile_estimates_only_what_it_queries(monkeypatch):
+    """A 4-motif compile profiles only the patterns costing asks for:
+    none of 5 vertices, and one ``apct.estimates`` per distinct
+    canonical skeleton queried."""
+    from repro import compiler, obs
+    queried = set()
+    query = APCT.query
+
+    def recording(self, p):
+        queried.add(Pattern(p.n, p.edges).canonical())
+        return query(self, p)
+
+    monkeypatch.setattr(APCT, "query", recording)
+    g = erdos_renyi(60, 5, seed=2)
+    before = obs.get("apct.estimates")
+    compiler.compile(motif_patterns(4), g, cache=False)
+    made = obs.get("apct.estimates") - before
+    assert queried and max(p.n for p in queried) <= 4
+    assert made == len(queried)
